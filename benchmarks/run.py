@@ -25,10 +25,13 @@ def _entry(name, fn, derive):
 
 
 def main() -> None:
+    from repro.launch.cache import enable_compile_cache
+
     from . import (bench_algo_compare, bench_cost, bench_filtered,
                    bench_ingest, bench_query, bench_runbooks, bench_scaleout,
                    bench_scaling, bench_serve, bench_sharded, bench_tiered)
 
+    enable_compile_cache()
     jobs = [
         ("serve_engine", bench_serve.main,
          lambda out: (f"speedup={out['speedup_batch16']['speedup']:.1f}x;"

@@ -22,7 +22,7 @@ class VectorWorkloadConfig:
     dim: int = 768
     M: int = 96  # PQ subspaces (96-byte codes, §2.1's OpenAI example rate)
     K: int = 256
-    R_slack: int = 41  # R=32 × slack 1.3
+    R: int = 32  # graph degree bound; R_slack = R × slack 1.3 = 41
     L_search: int = 100
     k: int = 10
     query_batch: int = 128
@@ -36,6 +36,10 @@ class VectorWorkloadConfig:
     # once so policy moves never recompile in steady state
     policy_widths: tuple[int, ...] = (1, 2, 4)
 
+    @property
+    def R_slack(self) -> int:
+        return int(self.R * 1.3)
+
 
 def config() -> VectorWorkloadConfig:
     return VectorWorkloadConfig()
@@ -43,7 +47,7 @@ def config() -> VectorWorkloadConfig:
 
 def smoke() -> VectorWorkloadConfig:
     return VectorWorkloadConfig(
-        name="cosmosann-smoke", total_vectors=2000, dim=32, M=8, R_slack=13,
+        name="cosmosann-smoke", total_vectors=2000, dim=32, M=8, R=10,
         L_search=20, k=5, query_batch=4,
     )
 

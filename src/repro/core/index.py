@@ -35,7 +35,6 @@ from . import graph as g
 from . import insert as imod
 from . import paginate as pgmod
 from . import pq as pqmod
-from . import prune as prmod
 from . import search as smod
 from .providers import ArrayProviderSet, Context, ProviderSet
 
@@ -261,20 +260,24 @@ class DiskANNIndex:
         """Mini-batch graph update (Alg 5): jitted search+prune, then one
         consolidated reverse-edge append per touched node."""
         cfg = self.cfg
-        neighbors, codes, versions, live, _ = self.pv.materialize(self.ctx)
+        n = len(slots)
+        neighbors, codes, versions, live = self.pv.materialize_graph(self.ctx)
+        # padded to a batch bucket (padding lanes repeat row 0 and are
+        # dropped): the ramp-up build's odd batch sizes share compiles
+        padded = jnp.asarray(smod.pad_batch_np(vecs, smod.next_bucket(n)))
         cand_ids, _cand_d, istats = imod.insert_candidates(
             neighbors, codes, versions, live, self._codebook_stack(),
-            jnp.asarray(vecs), jnp.int32(self.medoid),
+            padded, jnp.int32(self.medoid),
             L_build=cfg.L_build, metric=cfg.metric,
         )
         nbrs = np.asarray(
             imod.prune_batch(
-                codes, versions, self._codebook_stack(), jnp.asarray(vecs),
+                codes, versions, self._codebook_stack(), padded,
                 cand_ids, R=cfg.R, alpha=cfg.alpha, metric=cfg.metric,
             )
-        )  # (B, R)
-        stats.hops += float(np.asarray(istats.hops).sum())
-        stats.cmps += float(np.asarray(istats.cmps).sum())
+        )[:n]  # (B, R)
+        stats.hops += float(np.asarray(istats.hops)[:n].sum())
+        stats.cmps += float(np.asarray(istats.cmps)[:n].sum())
 
         rows = np.full((len(slots), cfg.R_slack), -1, np.int32)
         rows[:, : cfg.R] = nbrs
@@ -287,7 +290,7 @@ class DiskANNIndex:
             for b in nbrs[i]:
                 if b >= 0 and b != s:
                     rev.setdefault(int(b), []).append(int(s))
-        overflow: list[int] = []
+        overflow: list[tuple[int, list[int]]] = []
         for b, ps in rev.items():
             row = self.pv.neighbors[b]
             existing = set(int(x) for x in row[row >= 0])
@@ -296,43 +299,41 @@ class DiskANNIndex:
                 continue
             fitted = self.pv.append_neighbors(self.ctx, b, np.asarray(ps, np.int32))
             if fitted < len(ps):
-                row = self.pv.neighbors[b].copy()
-                merged = list(dict.fromkeys(list(row[row >= 0]) + ps))
-                self._prune_node(b, np.asarray(merged, np.int64))
-                overflow.append(b)
+                row = self.pv.neighbors[b]
+                overflow.append((b, list(dict.fromkeys(list(row[row >= 0]) + ps))))
+        if overflow:
+            self._prune_nodes(codes, versions, overflow)
 
-    def _decoded(self, ids: np.ndarray) -> np.ndarray:
-        """Quantized-space coordinates for pruning (§3.2)."""
-        codes, versions = self.pv.get_quant(self.ctx, ids)
-        out = np.zeros((len(ids), self.dim), np.float32)
-        for v, schema in enumerate(self.schemas):
-            m = versions == v
-            if m.any():
-                out[m] = np.asarray(pqmod.decode(schema, jnp.asarray(codes[m])))
-        return out
-
-    def _prune_node(self, node: int, cand: np.ndarray):
+    def _prune_nodes(self, codes: jax.Array, versions: jax.Array,
+                     overflow: list):
+        """Prune every overflowing row back to R in one device call; each
+        node appears once per mini-batch, so their prunes are independent."""
         cfg = self.cfg
         cap = cfg.R_slack + cfg.batch_size
-        cand = cand[:cap]
-        ids = np.full((cap,), -1, np.int64)
-        ids[: len(cand)] = cand
-        live_mask = self.pv.live[np.maximum(ids, 0)] & (ids >= 0)
-        ids = np.where(live_mask, ids, -1)
+        P = smod.next_bucket(len(overflow), (8, 16, 32, 64, 128, 256, 512))
+        nodes = np.zeros((P,), np.int32)
+        ids = np.full((P, cap), -1, np.int64)
+        for i, (b, cand) in enumerate(overflow):
+            nodes[i] = b
+            cand = cand[:cap]
+            ids[i, : len(cand)] = cand
+        ids = np.where(self.pv.live[np.maximum(ids, 0)] & (ids >= 0), ids, -1)
         pruned = np.asarray(
-            prmod.prune_with_vectors(
-                jnp.asarray(self._decoded(np.asarray([node]))[0]),
+            imod.prune_nodes(
+                codes, versions, self._codebook_stack(), jnp.asarray(nodes),
                 jnp.asarray(ids.astype(np.int32)),
-                jnp.asarray(self._decoded(np.maximum(ids, 0))),
-                alpha=cfg.alpha,
-                R=cfg.R,
-                metric=cfg.metric,
-                self_id=node,
+                R=cfg.R, alpha=cfg.alpha, metric=cfg.metric,
             )
-        )
-        row = np.full((cfg.R_slack,), -1, np.int32)
-        row[: cfg.R] = pruned
-        self.pv.set_neighbors(self.ctx, np.asarray([node]), row[None, :])
+        )[: len(overflow)]
+        rows = np.full((len(overflow), cfg.R_slack), -1, np.int32)
+        rows[:, : cfg.R] = pruned
+        self.pv.set_neighbors(self.ctx, nodes[: len(overflow)], rows)
+
+    def _decoded(self, ids: np.ndarray) -> jax.Array:
+        """Quantized-space coordinates for pruning (§3.2)."""
+        codes, versions = self.pv.get_quant(self.ctx, ids)
+        return pqmod.decode_versioned(self._codebook_stack(),
+                                      jnp.asarray(codes), jnp.asarray(versions))
 
     def _replace_one(self, doc_id: int, vec: np.ndarray):
         slot = self.doc_to_slot[doc_id]
@@ -399,10 +400,13 @@ class DiskANNIndex:
             if policy == "inplace" and self._graph_built:
                 neighbors, _, _, live, _ = self.pv.materialize(self.ctx)
                 old_nb = np.array(neighbors)  # copy: kernel donates its input
-                decoded = jnp.asarray(self._decoded(np.arange(self.count)))
-                pad = jnp.zeros((cfg.capacity - self.count, self.dim), jnp.float32)
+                # every slot decoded (one shape for any count), unused
+                # slots zeroed
+                decoded = jnp.where(
+                    (jnp.arange(cfg.capacity) < self.count)[:, None],
+                    self._decoded(np.arange(cfg.capacity)), 0.0)
                 new_nb = dmod.inplace_delete(
-                    neighbors, live, jnp.concatenate([decoded, pad]),
+                    neighbors, live, decoded,
                     jnp.int32(slot),
                     R=cfg.R, R_slack=cfg.R_slack, alpha=cfg.alpha,
                     c_replace=cfg.c_replace, metric=cfg.metric,

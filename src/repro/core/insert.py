@@ -81,13 +81,8 @@ def prune_batch(
 
     def decode_rows(ids):
         safe = jnp.maximum(ids, 0)
-        c = codes[safe]  # (C, M)
-        v = versions[safe].astype(jnp.int32)  # (C,)
-        cb = schemas_codebooks[v]  # (C, M, K, dsub)
-        picked = jnp.take_along_axis(
-            cb, c[:, :, None, None].astype(jnp.int32), axis=2
-        )[:, :, 0, :]  # (C, M, dsub)
-        return picked.reshape(ids.shape[0], -1)
+        return pqmod.decode_versioned(schemas_codebooks, codes[safe],
+                                      versions[safe])
 
     def one(vec, ids):
         cand_vecs = decode_rows(ids)
@@ -96,6 +91,35 @@ def prune_batch(
         )
 
     return jax.vmap(one)(new_vecs, cand_ids)
+
+
+@functools.partial(jax.jit, static_argnames=("R", "alpha", "metric"))
+def prune_nodes(
+    codes: jax.Array,
+    versions: jax.Array,
+    schemas_codebooks: jax.Array,  # (V, M, K, dsub)
+    nodes: jax.Array,  # (P,) existing nodes whose slack row overflowed
+    cand_ids: jax.Array,  # (P, C) row ∪ new reverse edges, -1 = invalid
+    *,
+    R: int,
+    alpha: float,
+    metric: str = "l2",
+) -> jax.Array:
+    """Overflow prune of Alg 5 for every overflowing node of a mini-batch
+    in one call, in quantized space like ``prune_batch``: (P, R) ids."""
+
+    def decode_rows(ids):
+        safe = jnp.maximum(ids, 0)
+        return pqmod.decode_versioned(schemas_codebooks, codes[safe],
+                                      versions[safe])
+
+    def one(node, ids):
+        return prmod.prune_with_vectors(
+            decode_rows(node[None])[0], ids, decode_rows(ids),
+            alpha=alpha, R=R, metric=metric, self_id=node,
+        )
+
+    return jax.vmap(one)(nodes, cand_ids)
 
 
 # ---------------------------------------------------------------------------
@@ -163,11 +187,8 @@ def insert_batch_jit(
 
     def decode_ids(ids):
         safe = jnp.maximum(ids, 0)
-        c = codes[safe]
-        v = versions[safe].astype(jnp.int32)
-        cb = schemas_codebooks[v]
-        picked = jnp.take_along_axis(cb, c[:, :, None, None].astype(jnp.int32), axis=2)[:, :, 0, :]
-        return picked.reshape(ids.shape[0], -1)
+        return pqmod.decode_versioned(schemas_codebooks, codes[safe],
+                                      versions[safe])
 
     def body(i, carry):
         nb, = carry

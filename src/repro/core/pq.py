@@ -154,12 +154,24 @@ def encode(schema: PQSchema, x: jax.Array) -> jax.Array:
 @jax.jit
 def decode(schema: PQSchema, codes: jax.Array) -> jax.Array:
     """(..., M) uint8 -> (..., D) float32 reconstruction."""
-    cent = schema.codebooks  # (M, K, dsub)
-    gathered = jnp.take_along_axis(
-        cent[None], codes.reshape(-1, schema.M)[:, :, None, None].astype(jnp.int32), axis=2
-    )  # (N, M, 1, dsub)
-    out = gathered[:, :, 0, :].reshape(*codes.shape[:-1], schema.dim)
-    return out
+    flat = codes.reshape(-1, schema.M).astype(jnp.int32)
+    sub = jnp.arange(schema.M)[None, :]
+    gathered = schema.codebooks[sub, flat]  # (N, M, dsub)
+    return gathered.reshape(*codes.shape[:-1], schema.dim)
+
+
+@jax.jit
+def decode_versioned(codebooks: jax.Array, codes: jax.Array,
+                     versions: jax.Array) -> jax.Array:
+    """(V, M, K, dsub) stacked codebooks, (C, M) codes, (C,) schema
+    versions -> (C, D) reconstruction. One gather of each row's centroids:
+    indexing ``codebooks[versions]`` first would copy a whole (M, K, dsub)
+    codebook per row."""
+    M, dsub = codebooks.shape[1], codebooks.shape[3]
+    sub = jnp.arange(M)[None, :]
+    picked = codebooks[versions.astype(jnp.int32)[:, None], sub,
+                       codes.astype(jnp.int32)]  # (C, M, dsub)
+    return picked.reshape(codes.shape[0], M * dsub)
 
 
 # ---------------------------------------------------------------------------
@@ -238,6 +250,22 @@ def multi_lut(schemas: tuple[PQSchema, ...], q: jax.Array, metric: str = "l2") -
     return jnp.stack([adc_lut(s, q, metric) for s in schemas], axis=0)
 
 
+def _adc_gather(luts, flat, ver):
+    """One gather of ``luts[ver, m, code]``: (C, M)."""
+    M = luts.shape[1]
+    return luts[ver[:, None], jnp.arange(M)[None, :], flat]
+
+
+def _adc_select(luts, flat, ver):
+    """``luts[ver, m, code]`` as a compare-select-sum over the K centroids:
+    each row has exactly one hit, so the sum is the table entry itself."""
+    V, _, K = luts.shape
+    hit = flat[:, :, None] == jnp.arange(K)
+    return sum(jnp.where(ver[:, None] == v,
+                         jnp.where(hit, luts[v], 0.0).sum(-1), 0.0)
+               for v in range(V))
+
+
 @jax.jit
 def adc_distance_versioned(luts: jax.Array, codes: jax.Array, versions: jax.Array) -> jax.Array:
     """ADC with a per-row schema version.
@@ -245,12 +273,16 @@ def adc_distance_versioned(luts: jax.Array, codes: jax.Array, versions: jax.Arra
     luts:     (V, M, K) float32
     codes:    (..., M) uint8
     versions: (...,) int — index into luts
+
+    Both forms give the same bits. A TPU pays per gathered scalar (one per
+    row and subspace, every search round), so it takes the fused vector
+    pass; a CPU gathers cheaply and would pay K× the work for the select.
     """
-    V, M, K = luts.shape
+    M = luts.shape[1]
     flat = codes.reshape(-1, M).astype(jnp.int32)
     ver = versions.reshape(-1).astype(jnp.int32)
-    lut_rows = luts[ver]  # (C, M, K)
-    d = jnp.take_along_axis(lut_rows, flat[:, :, None], axis=2)[..., 0]
+    d = jax.lax.platform_dependent(luts, flat, ver, cpu=_adc_gather,
+                                   default=_adc_select)  # (C, M)
     return d.sum(-1).reshape(codes.shape[:-1])
 
 

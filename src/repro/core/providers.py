@@ -55,6 +55,7 @@ class ProviderSet(Protocol):
     def set_full(self, ctx: Context, ids: np.ndarray, vecs: np.ndarray) -> None: ...
     def set_live(self, ctx: Context, ids: np.ndarray, value: bool) -> None: ...
     def materialize(self, ctx: Context): ...
+    def materialize_graph(self, ctx: Context): ...
     def barrier(self, name: str) -> None: ...
 
 
@@ -74,7 +75,7 @@ class ArrayProviderSet:
         # tiered residency ledger for the full-precision tier (ISSUE 10):
         # budget=None → fully resident → bit-identical pre-tier behaviour
         self.pages = PagedVectorStore(capacity, dim)
-        self._cache = None  # jnp materialization
+        self._cache: dict = {}  # field name -> jnp materialization
         self.write_count = 0
 
     def barrier(self, name: str) -> None:
@@ -82,22 +83,32 @@ class ArrayProviderSet:
         (StoreProviderSet overrides with the armed version)."""
 
     # -- invalidation ------------------------------------------------------
-    def _dirty(self):
-        self._cache = None
+    FIELDS = ("neighbors", "codes", "versions", "live", "vectors")
+
+    def _dirty(self, *fields: str):
+        """Drop the device copies of ``fields`` (all when none are named)."""
+        for name in fields or self.FIELDS:
+            self._cache.pop(name, None)
         self.write_count += 1
+
+    def _device(self, name: str):
+        arr = self._cache.get(name)
+        if arr is None:
+            arr = self._cache[name] = jnp.asarray(getattr(self, name))
+        return arr
 
     def materialize(self, ctx: Context = Context()):
         """jnp views of (neighbors, codes, versions, live, vectors) for the
-        jitted query/update kernels; rebuilt only after writes."""
-        if self._cache is None:
-            self._cache = (
-                jnp.asarray(self.neighbors),
-                jnp.asarray(self.codes),
-                jnp.asarray(self.versions),
-                jnp.asarray(self.live),
-                jnp.asarray(self.vectors),
-            )
-        return self._cache
+        jitted query/update kernels; each re-uploaded only after a write
+        to it."""
+        return tuple(self._device(name) for name in self.FIELDS)
+
+    def materialize_graph(self, ctx: Context = Context()):
+        """(neighbors, codes, versions, live) without the full-precision
+        vectors: the graph-update path never reads them, and re-uploading
+        the whole vector table after every write batch is most of a load's
+        host-to-device traffic."""
+        return tuple(self._device(name) for name in self.FIELDS[:4])
 
     # -- neighbor terms ------------------------------------------------------
     def get_neighbors(self, ctx: Context, ids):
@@ -105,7 +116,7 @@ class ArrayProviderSet:
 
     def set_neighbors(self, ctx: Context, ids, rows):
         self.neighbors[np.asarray(ids)] = rows
-        self._dirty()
+        self._dirty("neighbors")
 
     def append_neighbors(self, ctx: Context, node: int, new_ids):
         """Blind incremental append (the Bw-Tree forward-term fast path)."""
@@ -113,7 +124,7 @@ class ArrayProviderSet:
         deg = int((row >= 0).sum())
         n = min(len(new_ids), row.shape[0] - deg)
         row[deg : deg + n] = new_ids[:n]
-        self._dirty()
+        self._dirty("neighbors")
         return n  # how many fit; caller prunes on overflow
 
     # -- quantized terms ---------------------------------------------------
@@ -125,7 +136,7 @@ class ArrayProviderSet:
         ids = np.asarray(ids)
         self.codes[ids] = codes
         self.versions[ids] = versions
-        self._dirty()
+        self._dirty("codes", "versions")
 
     # -- full-precision vectors (document store role) ----------------------
     def get_full(self, ctx: Context, ids):
@@ -133,8 +144,8 @@ class ArrayProviderSet:
 
     def set_full(self, ctx: Context, ids, vecs):
         self.vectors[np.asarray(ids)] = vecs
-        self._dirty()
+        self._dirty("vectors")
 
     def set_live(self, ctx: Context, ids, value: bool):
         self.live[np.asarray(ids)] = value
-        self._dirty()
+        self._dirty("live")

@@ -321,10 +321,7 @@ def batch_greedy_search(
 def jit_cache_size() -> int:
     """Compiled-signature count of the batched-search entry (recompile
     telemetry for the serving layer; see serve/vector_engine.py)."""
-    try:
-        return int(_batched_search_entry._cache_size())
-    except AttributeError:  # very old/new jit wrappers
-        return -1
+    return int(_batched_search_entry._cache_size())
 
 
 # ---------------------------------------------------------------------------

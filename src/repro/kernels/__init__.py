@@ -12,14 +12,25 @@ those loops for the TPU memory hierarchy:
     flat_l2      tiled full-precision distance matrix (re-rank / brute force)
 
 Each subpackage: kernel.py (pl.pallas_call + BlockSpec), ops.py (jit'd
-wrapper with an interpret-mode fallback for CPU), ref.py (pure-jnp oracle).
-TPU is the *target*; on this CPU container kernels run under interpret=True
-and are validated against the oracles across shape/dtype sweeps in
-tests/test_kernels.py.
+wrapper), ref.py (pure-jnp oracle). On a TPU the kernels compile through
+Mosaic; on any other backend they run under ``interpret=True`` and are
+validated against the oracles across shape/dtype sweeps in
+tests/test_kernels.py. tests/test_tpu_compile.py compiles them for a
+described v5e at the paper's widths.
 """
-from .pq_adc import ops as pq_adc_ops
-from .pq_encode import ops as pq_encode_ops
-from .topk_select import ops as topk_ops
-from .flat_l2 import ops as flat_l2_ops
+import jax
 
-__all__ = ["pq_adc_ops", "pq_encode_ops", "topk_ops", "flat_l2_ops"]
+
+def interpret_default() -> bool:
+    """Pallas kernels compile only for TPU; elsewhere they are interpreted.
+    (Defined before the subpackage imports below, which use it.)"""
+    return jax.default_backend() != "tpu"
+
+
+from .pq_adc import ops as pq_adc_ops  # noqa: E402
+from .pq_encode import ops as pq_encode_ops  # noqa: E402
+from .topk_select import ops as topk_ops  # noqa: E402
+from .flat_l2 import ops as flat_l2_ops  # noqa: E402
+
+__all__ = ["interpret_default", "pq_adc_ops", "pq_encode_ops", "topk_ops",
+           "flat_l2_ops"]

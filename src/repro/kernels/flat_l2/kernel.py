@@ -8,7 +8,9 @@ Classic three-level matmul tiling: grid (B/Bb, N/Nb, D/Db) with the
 contraction dimension innermost; the output block is revisited across the
 D-steps and accumulated in place (f32). Block shapes keep every operand in
 VMEM with MXU-aligned (multiple-of-128) matmul dims; norms are added on the
-final contraction step so the kernel emits finished distances.
+final contraction step so the kernel emits finished distances. The norms
+ride in as 2-D column / row blocks, (Bb, 1) and (1, Nb): Mosaic lays out
+1-D operands differently from XLA and refuses them.
 """
 from __future__ import annotations
 
@@ -26,14 +28,16 @@ def _flat_kernel(q_ref, x_ref, q2_ref, x2_ref, out_ref, *, n_dsteps: int, metric
     def _init():
         out_ref[...] = jnp.zeros_like(out_ref)
 
-    out_ref[...] += jnp.dot(
-        q_ref[...], x_ref[...].T, preferred_element_type=jnp.float32
+    out_ref[...] += jax.lax.dot_general(
+        q_ref[...], x_ref[...], (((1,), (1,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST,
+        preferred_element_type=jnp.float32,
     )
 
     @pl.when(k == n_dsteps - 1)
     def _finish():
         if metric == "l2":
-            out_ref[...] = q2_ref[...].reshape(-1, 1) + x2_ref[...].reshape(1, -1) - 2.0 * out_ref[...]
+            out_ref[...] = q2_ref[...] + x2_ref[...] - 2.0 * out_ref[...]
         else:  # ip: negative inner product
             out_ref[...] = -out_ref[...]
 
@@ -64,8 +68,8 @@ def flat_l2_pallas(
     xp = pad_to(x.astype(jnp.float32), bn, bd)
     Bp, Dp = qp.shape
     Np = xp.shape[0]
-    q2 = jnp.sum(qp * qp, -1)
-    x2 = jnp.sum(xp * xp, -1)
+    q2 = jnp.sum(qp * qp, -1, keepdims=True)  # (Bp, 1)
+    x2 = jnp.sum(xp * xp, -1)[None, :]  # (1, Np)
     n_dsteps = Dp // bd
 
     out = pl.pallas_call(
@@ -74,8 +78,8 @@ def flat_l2_pallas(
         in_specs=[
             pl.BlockSpec((bb, bd), lambda i, j, k: (i, k)),
             pl.BlockSpec((bn, bd), lambda i, j, k: (j, k)),
-            pl.BlockSpec((bb,), lambda i, j, k: (i,)),
-            pl.BlockSpec((bn,), lambda i, j, k: (j,)),
+            pl.BlockSpec((bb, 1), lambda i, j, k: (i, 0)),
+            pl.BlockSpec((1, bn), lambda i, j, k: (0, j)),
         ],
         out_specs=pl.BlockSpec((bb, bn), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((Bp, Np), jnp.float32),

@@ -9,17 +9,22 @@ CPU DiskANN does this as L1-cache scalar lookups; a TPU has no scalar
 gather path worth using, but it has an MXU. We rewrite the lookup as a
 one-hot contraction
 
-    out[b, c] = Σ_m  onehot(codes[c, m]) · lut[b, m, :]
+    out[b, c] = Σ_m  lut[b, m, :] · onehot(codes[c, m])
 
-and tile it: the full LUT for one query (M·K·4 B ≈ 16–64 KiB) lives in VMEM
-across the whole scan; codes stream through VMEM in (Cb, M) tiles. The
-one-hot never materializes in HBM — it is built in-register per (tile, m)
-and fed straight to the MXU as a (Cb, K) × (K,) product.
+and tile it: a (Bb, M, K) block of LUTs lives in VMEM across the whole
+scan; codes stream through VMEM in (M, Cb) tiles. The one-hot never
+materializes in HBM — it is built per (tile, m) as a (K, Cb) compare
+against a sublane iota and fed straight to the MXU as a
+(Bb, K) × (K, Cb) product at HIGHEST precision, which reproduces each f32
+table entry exactly (every one-hot column holds a single 1).
 
-Grid: (B, C/Cb) — one LUT residency per query row, codes tiles innermost so
-the LUT block is reused across the entire scan (arithmetic intensity
-M·Cb / (Cb·M + M·K) ≈ 1 FLOP/byte of code traffic, i.e. memory-bound by
-design, matching the paper's "quantized vector access dominates" profile).
+Layout: both operands are laid out so that the subspace index ``m`` walks
+a leading, untiled dimension — LUTs as (M, B, K), codes as (M, 1, C) —
+which Mosaic indexes dynamically; the tiled (last two) block dims are
+(Bb, K) and (1, Cb) with Bb a multiple of 8 and Cb of 128.
+
+Grid: (B/Bb, C/Cb) — codes tiles innermost so the LUT block is reused
+across the entire scan.
 """
 from __future__ import annotations
 
@@ -30,48 +35,49 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 
-def _adc_kernel(lut_ref, codes_ref, out_ref, *, K: int):
-    """lut_ref: (1, M, K) f32; codes_ref: (Cb, M) i32; out_ref: (1, Cb) f32."""
-    codes = codes_ref[...]  # (Cb, M)
-    M = codes.shape[1]
-    Cb = codes.shape[0]
+def _adc_kernel(lut_ref, codes_ref, out_ref):
+    """lut_ref: (M, Bb, K) f32; codes_ref: (M, 1, Cb) i32; out_ref: (Bb, Cb)."""
+    M, _, K = lut_ref.shape
+    Cb = codes_ref.shape[2]
+    rows = jax.lax.broadcasted_iota(jnp.int32, (K, Cb), 0)
 
     def body(m, acc):
-        row = lut_ref[0, m, :]  # (K,)
-        onehot = (codes[:, m][:, None] == jax.lax.iota(jnp.int32, K)[None, :])
-        return acc + jnp.dot(onehot.astype(jnp.float32), row)
+        onehot = (rows == codes_ref[m]).astype(jnp.float32)  # (K, Cb)
+        return acc + jnp.dot(lut_ref[m], onehot,
+                             precision=jax.lax.Precision.HIGHEST,
+                             preferred_element_type=jnp.float32)
 
-    acc = jax.lax.fori_loop(0, M, body, jnp.zeros((Cb,), jnp.float32))
-    out_ref[0, :] = acc
+    out_ref[...] = jax.lax.fori_loop(
+        0, M, body, jnp.zeros(out_ref.shape, jnp.float32))
 
 
-@functools.partial(jax.jit, static_argnames=("block_c", "interpret"))
+@functools.partial(jax.jit, static_argnames=("block_b", "block_c", "interpret"))
 def pq_adc_pallas(
     lut: jax.Array,  # (B, M, K) float32
     codes: jax.Array,  # (C, M) uint8/int32
     *,
+    block_b: int = 8,
     block_c: int = 512,
     interpret: bool = False,
 ) -> jax.Array:
     """Distances (B, C) via the tiled one-hot ADC kernel."""
     B, M, K = lut.shape
     C = codes.shape[0]
-    codes_i = codes.astype(jnp.int32)
-
-    # pad C to a multiple of block_c
-    Cp = ((C + block_c - 1) // block_c) * block_c
-    if Cp != C:
-        codes_i = jnp.pad(codes_i, ((0, Cp - C), (0, 0)))
+    Bp = -(-B // block_b) * block_b
+    Cp = -(-C // block_c) * block_c
+    lut_t = jnp.pad(lut.astype(jnp.float32).transpose(1, 0, 2),
+                    ((0, 0), (0, Bp - B), (0, 0)))
+    codes_t = jnp.pad(codes.astype(jnp.int32).T, ((0, 0), (0, Cp - C)))
 
     out = pl.pallas_call(
-        functools.partial(_adc_kernel, K=K),
-        grid=(B, Cp // block_c),
+        _adc_kernel,
+        grid=(Bp // block_b, Cp // block_c),
         in_specs=[
-            pl.BlockSpec((1, M, K), lambda b, c: (b, 0, 0)),
-            pl.BlockSpec((block_c, M), lambda b, c: (c, 0)),
+            pl.BlockSpec((M, block_b, K), lambda b, c: (0, b, 0)),
+            pl.BlockSpec((M, 1, block_c), lambda b, c: (0, 0, c)),
         ],
-        out_specs=pl.BlockSpec((1, block_c), lambda b, c: (b, c)),
-        out_shape=jax.ShapeDtypeStruct((B, Cp), jnp.float32),
+        out_specs=pl.BlockSpec((block_b, block_c), lambda b, c: (b, c)),
+        out_shape=jax.ShapeDtypeStruct((Bp, Cp), jnp.float32),
         interpret=interpret,
-    )(lut, codes_i)
-    return out[:, :C]
+    )(lut_t, codes_t.reshape(M, 1, Cp))
+    return out[:B, :C]

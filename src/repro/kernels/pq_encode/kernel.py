@@ -1,12 +1,18 @@
 """pq_encode — PQ encoding (nearest centroid per subspace) tiled for TPU.
 
 Workload: x (N, D) float32, codebooks (M, K, dsub) → codes (N, M).
-Per subspace m: scores (Nb, K) = ‖x_m‖² − 2·x_m·C_mᵀ + ‖c‖² → argmin.
+Per subspace m: scores (K, Nb) = ‖c‖² − 2·C_m·x_mᵀ (+ ‖x_m‖²) → argmin
+over K.
 
-Grid: (N/Nb, M). Per step the (Nb, dsub) slice of x and the (K, dsub)
-codebook for subspace m sit in VMEM; the −2·x·Cᵀ term is an MXU matmul.
-The ‖x‖² term is constant across K and irrelevant to the argmin, so the
-kernel skips it — scores are shifted but the codes are identical.
+Layout: x is fed transposed per subspace, as (M, dsub, N), so a block
+(1, dsub, Nb) keeps the vectors on the 128-wide lane axis and dsub on the
+sublanes, and the (1, K, dsub) codebook block covers its array's last two
+dims whole. Each grid step (N/Nb, M) is one (K, dsub) × (dsub, Nb) MXU
+product at HIGHEST precision; the argmin runs down the K sublanes as a
+min-reduce plus an iota compare (first index wins, like ``jnp.argmin``),
+and one (1, 1, Nb) row of codes is stored per step. The ‖x‖² term is
+constant across K and irrelevant to the argmin, so the kernel skips it —
+scores are shifted but the codes are identical.
 """
 from __future__ import annotations
 
@@ -18,11 +24,17 @@ from jax.experimental import pallas as pl
 
 
 def _encode_kernel(x_ref, cent_ref, out_ref):
-    """x_ref: (Nb, 1, dsub); cent_ref: (1, K, dsub); out_ref: (Nb, 1) i32."""
-    x = x_ref[:, 0, :]  # (Nb, dsub)
+    """x_ref: (1, dsub, Nb); cent_ref: (1, K, dsub); out_ref: (1, 1, Nb) i32."""
+    x = x_ref[0]  # (dsub, Nb)
     cent = cent_ref[0]  # (K, dsub)
-    scores = -2.0 * jnp.dot(x, cent.T) + jnp.sum(cent * cent, -1)[None, :]
-    out_ref[:, 0] = jnp.argmin(scores, axis=-1).astype(jnp.int32)
+    K = cent.shape[0]
+    cross = jnp.dot(cent, x, precision=jax.lax.Precision.HIGHEST,
+                    preferred_element_type=jnp.float32)  # (K, Nb)
+    scores = jnp.sum(cent * cent, axis=1, keepdims=True) - 2.0 * cross
+    best = jnp.min(scores, axis=0, keepdims=True)  # (1, Nb)
+    rows = jax.lax.broadcasted_iota(jnp.int32, scores.shape, 0)
+    code = jnp.min(jnp.where(scores == best, rows, K), axis=0, keepdims=True)
+    out_ref[0] = code
 
 
 @functools.partial(jax.jit, static_argnames=("block_n", "interpret"))
@@ -36,19 +48,19 @@ def pq_encode_pallas(
     N, D = x.shape
     M, K, dsub = codebooks.shape
     assert D == M * dsub
-    Np = ((N + block_n - 1) // block_n) * block_n
-    xp = jnp.pad(x, ((0, Np - N), (0, 0))) if Np != N else x
+    Np = -(-N // block_n) * block_n
+    xp = jnp.pad(x.astype(jnp.float32), ((0, Np - N), (0, 0)))
+    x_t = xp.reshape(Np, M, dsub).transpose(1, 2, 0)  # (M, dsub, Np)
 
     out = pl.pallas_call(
         _encode_kernel,
         grid=(Np // block_n, M),
         in_specs=[
-            # x viewed as (N, M, dsub): block (Nb, 1, dsub) → squeeze in spec
-            pl.BlockSpec((block_n, 1, dsub), lambda n, m: (n, m, 0)),
+            pl.BlockSpec((1, dsub, block_n), lambda n, m: (m, 0, n)),
             pl.BlockSpec((1, K, dsub), lambda n, m: (m, 0, 0)),
         ],
-        out_specs=pl.BlockSpec((block_n, 1), lambda n, m: (n, m)),
-        out_shape=jax.ShapeDtypeStruct((Np, M), jnp.int32),
+        out_specs=pl.BlockSpec((1, 1, block_n), lambda n, m: (m, 0, n)),
+        out_shape=jax.ShapeDtypeStruct((M, 1, Np), jnp.int32),
         interpret=interpret,
-    )(xp.reshape(Np, M, dsub), codebooks)
-    return out[:N].astype(jnp.uint8)
+    )(x_t, codebooks.astype(jnp.float32))
+    return out.reshape(M, Np)[:, :N].T.astype(jnp.uint8)

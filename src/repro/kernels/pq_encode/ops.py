@@ -3,7 +3,7 @@ from __future__ import annotations
 
 import jax
 
-from ... import compat
+from .. import interpret_default
 from .kernel import pq_encode_pallas
 from .ref import pq_encode_ref
 
@@ -12,7 +12,7 @@ def pq_encode(x: jax.Array, codebooks: jax.Array, *, block_n: int = 256,
               use_pallas: bool | None = None) -> jax.Array:
     if use_pallas is None:
         use_pallas = True
-    interpret = compat.pallas_interpret_default()
+    interpret = interpret_default()
     if not use_pallas:
         return pq_encode_ref(x, codebooks)
     return pq_encode_pallas(x, codebooks, block_n=block_n, interpret=interpret)

@@ -6,28 +6,28 @@ and carries only the data-parallel gradient all-reduce (see
 models/sharding.py). Defined as a function so importing this module never
 touches jax device state (the dry-run must set XLA_FLAGS first).
 
-Construction goes through repro.compat.make_mesh: axis types (Auto) are
-passed only on JAX versions whose ``jax.make_mesh`` accepts them.
+Every mesh is built with Auto axis types: the repo shards through explicit
+in/out shardings, not sharding-in-types (``jax.make_mesh`` defaults to
+Explicit axes).
 """
 from __future__ import annotations
 
 import jax
-
-from .. import compat
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> jax.sharding.Mesh:
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return compat.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_host_mesh(shape: tuple[int, ...] = None, axes: tuple[str, ...] = None):
     """Small mesh over whatever devices exist (tests / examples)."""
-    n = len(jax.devices())
     if shape is None:
-        shape, axes = (n,), ("data",)
-    return compat.make_mesh(shape, axes)
+        shape, axes = (len(jax.devices()),), ("data",)
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_serve_mesh(devices: int = None) -> jax.sharding.Mesh:
@@ -38,4 +38,4 @@ def make_serve_mesh(devices: int = None) -> jax.sharding.Mesh:
     ``XLA_FLAGS=--xla_force_host_platform_device_count=N`` to emulate a
     pod)."""
     n = devices or len(jax.devices())
-    return compat.make_mesh((n,), ("data",))
+    return jax.make_mesh((n,), ("data",), axis_types=(AxisType.Auto,))

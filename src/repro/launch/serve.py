@@ -18,6 +18,7 @@ from ..core import GraphConfig
 from ..models import model as M
 from ..serve import (EngineConfig, ServeEngine, VectorCollectionService,
                      VectorQuery)
+from .cache import enable_compile_cache
 
 
 def main(argv=None):
@@ -53,6 +54,7 @@ def main(argv=None):
                     help="dump the labeled metrics registry in Prometheus "
                          "text exposition format")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)
     if not cfg.has_decode:

@@ -24,7 +24,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from .. import compat
 from .config import MLAConfig, ModelConfig
 from .layers import apply_rope, dense_init, rmsnorm, rmsnorm_init
 
@@ -73,8 +72,8 @@ def _qkv(params, cfg: ModelConfig, x, positions):
 def _cp_constrain(x: jax.Array, seq_axis: int) -> jax.Array:
     """Shard dim `seq_axis` over the `model` mesh axis (context parallelism)
     under the ambient mesh; no-op without one or when indivisible."""
-    m = compat.get_abstract_mesh()
-    if m is None or "model" not in (m.axis_names or ()):
+    m = jax.sharding.get_abstract_mesh()
+    if "model" not in m.axis_names:
         return x
     if x.shape[seq_axis] % m.shape["model"] != 0:
         return x

@@ -23,7 +23,6 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding
 from jax.sharding import PartitionSpec as P
 
-from .. import compat
 
 from .config import ModelConfig
 
@@ -154,8 +153,8 @@ def constrain_batch_dim(x: jax.Array, extra: tuple = ()) -> jax.Array:
     replicating layer inputs across the mesh (measured: smollm train went
     from fully-replicated compute to properly sharded once constrained).
     """
-    m = compat.get_abstract_mesh()
-    if m is None or not m.axis_names:
+    m = jax.sharding.get_abstract_mesh()
+    if not m.axis_names:
         return x
     axes = tuple(a for a in ("pod", "data") if a in m.axis_names)
     if not axes:

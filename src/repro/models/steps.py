@@ -15,7 +15,6 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding
 from jax.sharding import PartitionSpec as P
 
-from .. import compat
 from ..train.optimizer import OptConfig, OptState, adamw_update, init_opt_state
 from . import model as M
 from .config import ModelConfig
@@ -78,7 +77,7 @@ def make_train_step(
     batch_sh = _batch_shardings(batch_shapes, mesh)
 
     def step(state: TrainState, batch: dict):
-        _ctx = compat.use_abstract_mesh(mesh)
+        _ctx = jax.sharding.use_abstract_mesh(mesh.abstract_mesh)
         _ctx.__enter__()
         if accum > 1:
             def micro(c, mb):
@@ -159,7 +158,7 @@ def make_prefill_step(
     cache_sh = _named(cspecs, mesh)
 
     def step(params, batch):
-        with compat.use_abstract_mesh(mesh):
+        with jax.sharding.use_abstract_mesh(mesh.abstract_mesh):
             cache = M.init_cache(cfg, B, s_max, cache_dtype)
             logits, cache = M.prefill(params, cfg, batch, cache)
             return logits, cache
@@ -208,7 +207,7 @@ def make_decode_step(
     len_sh = NamedSharding(mesh, P())
 
     def step(params, cache, tokens, cache_len):
-        with compat.use_abstract_mesh(mesh):
+        with jax.sharding.use_abstract_mesh(mesh.abstract_mesh):
             logits, new_cache = M.decode_step(params, cfg, tokens, cache, cache_len)
             return logits, new_cache
 

@@ -33,9 +33,9 @@ from typing import Optional, Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import NamedSharding
 from jax.sharding import PartitionSpec as P
 
-from .. import compat
 from ..core import flat as fmod
 from ..core import paginate as pgmod
 from ..core import pq as pqmod
@@ -152,8 +152,10 @@ def batched_fanout_search(
             continue
         try:
             ids, dists, ru, stats = p.search_batch(queries, k, L, **kw)
-        except CrashError:
-            raise  # an injected process kill is not a partition fault
+        except (CrashError, jax.errors.JaxRuntimeError):
+            # an injected process kill, or a device fault (a compile
+            # failure, HBM exhausted), is not a partition fault
+            raise
         except Exception as e:  # noqa: BLE001 — degrade, don't collapse
             failed.append((int(p.pid), f"{type(e).__name__}: {e}"))
             continue
@@ -256,8 +258,10 @@ def batched_filtered_fanout_search(
                 queries, k, mask, L=L, term_reads=nreads,
                 filter_words=words, **kw
             )
-        except CrashError:
-            raise  # an injected process kill is not a partition fault
+        except (CrashError, jax.errors.JaxRuntimeError):
+            # an injected process kill, or a device fault (a compile
+            # failure, HBM exhausted), is not a partition fault
+            raise
         except Exception as e:  # noqa: BLE001 — degrade, don't collapse
             failed.append((int(p.pid), f"{type(e).__name__}: {e}"))
             continue
@@ -586,15 +590,15 @@ def distributed_search_fn(
         out_ids = jnp.take_along_axis(flat_i, pos, axis=1)
         return out_ids, -neg
 
-    shmapped = compat.shard_map(
+    shmapped = jax.shard_map(
         local_search,
-        mesh,
+        mesh=mesh,
         in_specs=(
             spec_sharded, spec_sharded, spec_sharded, spec_sharded,
             spec_sharded, spec_sharded, spec_sharded, spec_sharded, spec_repl,
         ),
         out_specs=(spec_repl, spec_repl),
-        check=False,
+        check_vma=False,
     )
     return jax.jit(shmapped)
 
@@ -610,13 +614,7 @@ def spmd_jit_cache_size() -> int:
     """Compiled-signature count across every SpmdFanout program. Feeds
     ``serve.vector_engine.serving_jit_cache_size`` so the zero-recompile
     contract covers the spmd dispatch path too."""
-    n = 0
-    for f in _SPMD_PROGRAMS:
-        try:
-            n += int(f._cache_size())
-        except AttributeError:
-            pass
-    return n
+    return sum(int(f._cache_size()) for f in _SPMD_PROGRAMS)
 
 
 class SpmdFanout:
@@ -669,18 +667,27 @@ class SpmdFanout:
         # pad the partition axis to the mesh size by repeating partition 0
         # (its results are computed and discarded — never merged)
         all_p = list(prog_parts) + [prog_parts[0]] * (P_pad - len(prog_parts))
-        mats = [p.index.pv.materialize(p.index.ctx) for p in all_p]
+        sharding = NamedSharding(self.mesh, P(tuple(self.mesh.axis_names)))
+
+        def place(rows: list) -> jax.Array:
+            # each device is handed only its own partitions' rows, straight
+            # from host memory: no partition is staged on another chip
+            return jax.make_array_from_callback(
+                (len(rows),) + np.shape(rows[0]), sharding,
+                lambda idx: np.stack(rows[idx[0]]))
+
+        pvs = [p.index.pv for p in all_p]
         arrs = dict(
-            neighbors=jnp.stack([m[0] for m in mats]),
-            codes=jnp.stack([m[1] for m in mats]),
-            versions=jnp.stack([m[2] for m in mats]),
-            live=jnp.stack([m[3] for m in mats]),
-            vectors=jnp.stack([m[4] for m in mats]),
+            neighbors=place([pv.neighbors for pv in pvs]),
+            codes=place([pv.codes for pv in pvs]),
+            versions=place([pv.versions for pv in pvs]),
+            live=place([pv.live for pv in pvs]),
+            vectors=place([pv.vectors for pv in pvs]),
             # x64 is disabled: the doc-id table rides along as int32 and
             # widens back to int64 on the host
-            slot_to_doc=jnp.asarray(np.stack(
-                [p.index.slot_to_doc for p in all_p]).astype(np.int32)),
-            medoid=jnp.asarray([p.index.medoid for p in all_p], jnp.int32),
+            slot_to_doc=place([p.index.slot_to_doc.astype(np.int32)
+                               for p in all_p]),
+            medoid=place([np.int32(p.index.medoid) for p in all_p]),
         )
         self._stacks[key] = (stamp, arrs)
         return arrs
@@ -714,11 +721,11 @@ class SpmdFanout:
                 neighbors, codes, versions, live, vectors, s2d, medoid, luts
             )
 
-        fn = jax.jit(compat.shard_map(
-            local, self.mesh,
+        fn = jax.jit(jax.shard_map(
+            local, mesh=self.mesh,
             in_specs=(sh,) * 8 + (rep,),
             out_specs=(sh,) * 6,
-            check=False,
+            check_vma=False,
         ))
         self._programs[key] = fn
         _SPMD_PROGRAMS.append(fn)
@@ -768,8 +775,10 @@ class SpmdFanout:
                 kw["beam_width"] = W
             try:
                 ids, dists, ru, stats = p.search_batch(queries, k, L, **kw)
-            except CrashError:
-                raise  # an injected process kill is not a partition fault
+            except (CrashError, jax.errors.JaxRuntimeError):
+                # an injected process kill, or a device fault (a compile
+                # failure, HBM exhausted), is not a partition fault
+                raise
             except Exception as e:  # noqa: BLE001 — degrade, don't collapse
                 down.add(i)
                 failed.append((int(p.pid), f"{type(e).__name__}: {e}"))

@@ -42,6 +42,7 @@ import dataclasses
 from collections import deque
 from typing import Any, Callable, Optional, Sequence
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -66,12 +67,8 @@ def serving_jit_cache_size() -> int:
     """Total compiled-signature count across the serving hot path (graph
     search + re-rank + brute force + the spmd fan-out program). Flat
     trajectory == zero recompiles."""
-    n = max(smod.jit_cache_size(), 0)
-    for f in (fmod.brute_force, fmod.rerank):
-        try:
-            n += int(f._cache_size())
-        except AttributeError:
-            pass
+    n = smod.jit_cache_size()
+    n += sum(int(f._cache_size()) for f in (fmod.brute_force, fmod.rerank))
     return n + spmd_jit_cache_size()
 
 
@@ -805,8 +802,10 @@ class VectorServeEngine:
                     jnp.asarray(padded), jnp.asarray(pv.vectors),
                     jnp.asarray(scan_mask), k=k, metric=p.index.cfg.metric,
                 )
-            except CrashError:
-                raise  # injected process kill: never degrade past it
+            except (CrashError, jax.errors.JaxRuntimeError):
+                # injected process kill, or a device fault (a compile
+                # failure, HBM exhausted): never degrade past it
+                raise
             except Exception as e:  # noqa: BLE001 — degrade, don't fail
                 failed.append((p.pid, f"{type(e).__name__}: {e}"))
                 continue

@@ -1,124 +1,49 @@
-"""repro.compat: mesh construction, feature detection, shard_map shim,
-abstract-mesh contexts, and Pallas dynamic-slice helpers — exercised on
-whatever JAX version is installed (both branches must behave identically
-from the caller's point of view)."""
+"""The JAX surfaces the repo builds on, exercised on the installed JAX:
+Auto-typed meshes, ``jax.shard_map``, and the kernels' interpret switch."""
 import jax
 import jax.numpy as jnp
 import numpy as np
-import pytest
-from jax.experimental import pallas as pl
+from jax.sharding import AxisType
 from jax.sharding import PartitionSpec as P
 
-from repro import compat
-
-
-def test_version_parsing_and_ordering():
-    assert compat.JAX_VERSION == compat._version_tuple(jax.__version__)
-    assert compat.jax_at_least(0, 4)
-    assert not compat.jax_at_least(99, 0)
-    # suffixes like "0.4.38.dev20250101" must not crash
-    assert compat._version_tuple("0.4.38.dev20250101")[:3] == (0, 4, 38)
-    assert compat._version_tuple("garbage") == (0,)
-    # pre-release digits must not concatenate: 38rc1 is 38, not 381
-    assert compat._version_tuple("0.4.38rc1") == (0, 4, 38)
-    assert compat._version_tuple("0.7.0rc1") == (0, 7, 0)
-
-
-def test_feature_detection_consistency():
-    assert compat.supports_axis_types() == compat.has_api(jax.sharding, "AxisType")
-    assert compat.supports_abstract_mesh_context() == compat.has_api(
-        jax.sharding, "use_abstract_mesh")
-    # deprecation-raising getattr must not leak
-    class Raises:
-        def __getattr__(self, name):
-            raise AttributeError(name)
-    assert not compat.has_api(Raises(), "anything")
+from repro.kernels import interpret_default
+from repro.launch.mesh import make_host_mesh
 
 
 def test_make_mesh_host_devices():
     n = len(jax.devices())
-    mesh = compat.make_mesh((n,), ("data",))
+    mesh = make_host_mesh((n,), ("data",))
     assert isinstance(mesh, jax.sharding.Mesh)
     assert mesh.axis_names == ("data",)
     assert mesh.shape["data"] == n
+    assert mesh.axis_types == (AxisType.Auto,)
     # multi-axis on a single device
-    mesh2 = compat.make_mesh((1, 1), ("data", "model"))
+    mesh2 = make_host_mesh((1, 1), ("data", "model"))
     assert mesh2.axis_names == ("data", "model")
 
 
 def test_make_mesh_usable_for_sharding():
-    mesh = compat.make_mesh((len(jax.devices()),), ("data",))
+    mesh = make_host_mesh()
     sh = jax.sharding.NamedSharding(mesh, P())
     x = jax.device_put(jnp.arange(8.0), sh)
     np.testing.assert_array_equal(np.asarray(x), np.arange(8.0))
 
 
-def test_use_abstract_mesh_is_context_manager():
-    mesh = compat.make_mesh((len(jax.devices()),), ("data",))
-    with compat.use_abstract_mesh(mesh):
-        y = jnp.square(jnp.arange(4.0))
-    np.testing.assert_array_equal(np.asarray(y), [0.0, 1.0, 4.0, 9.0])
-
-
-def test_get_abstract_mesh_none_or_mesh():
-    m = compat.get_abstract_mesh()
-    # outside any mesh context: None, or an empty-axis ambient mesh filtered
-    # to None by the helper
-    assert m is None or m.axis_names
-
-
 def test_shard_map_runs_and_matches_reference():
     n = len(jax.devices())
-    mesh = compat.make_mesh((n,), ("data",))
+    mesh = make_host_mesh()
     x = jnp.arange(4 * n, dtype=jnp.float32).reshape(n, 4)
 
     def local(v):
         s = jax.lax.psum(jnp.sum(v), "data")
         return v * 2.0 + s
 
-    fn = jax.jit(compat.shard_map(
-        local, mesh, in_specs=P("data"), out_specs=P("data")))
+    fn = jax.jit(jax.shard_map(
+        local, mesh=mesh, in_specs=P("data"), out_specs=P("data"),
+        check_vma=False))
     out = fn(x)
     np.testing.assert_allclose(np.asarray(out), np.asarray(x) * 2.0 + float(x.sum()))
 
 
-def test_ds_helpers_build_slices():
-    assert isinstance(compat.ds(0, 4), compat.Slice)
-    assert isinstance(compat.ds1(3), compat.Slice)
-    s = compat.ds1(2)
-    assert s.size == 1
-    mixed = compat.ds_index(0, compat.ds(1, 2), 5)
-    assert all(isinstance(i, compat.Slice) for i in mixed)
-    assert (mixed[1].size, mixed[0].size, mixed[2].size) == (2, 1, 1)
-    # python slices and non-scalar arrays pass through unchanged
-    passthru = compat.ds_index(slice(None), np.arange(3), 1)
-    assert passthru[0] == slice(None)
-    assert isinstance(passthru[1], np.ndarray)
-    assert isinstance(passthru[2], compat.Slice)
-    # 0-d traced/array scalars are wrapped like ints
-    assert isinstance(compat.ds_index(np.int32(2))[0], compat.Slice)
-
-
-def test_ds_helpers_in_pallas_interpret():
-    """pl.load/pl.store with compat-built indices run under interpret mode
-    (raw ints in these index tuples are exactly what 0.4.x rejects)."""
-
-    def kernel(x_ref, o_ref):
-        def body(i, _):
-            row = pl.load(x_ref, (compat.ds1(0), compat.ds1(i)))
-            pl.store(o_ref, compat.ds_index(0, pl.ds(i, 1)), row + 1.0)
-            return 0
-
-        jax.lax.fori_loop(0, x_ref.shape[1], body, 0)
-
-    x = jnp.arange(8.0, dtype=jnp.float32).reshape(1, 8)
-    out = pl.pallas_call(
-        kernel,
-        out_shape=jax.ShapeDtypeStruct((1, 8), jnp.float32),
-        interpret=True,
-    )(x)
-    np.testing.assert_array_equal(np.asarray(out), np.asarray(x) + 1.0)
-
-
 def test_pallas_interpret_default_matches_backend():
-    assert compat.pallas_interpret_default() == (jax.default_backend() != "tpu")
+    assert interpret_default() == (jax.default_backend() != "tpu")

@@ -51,8 +51,8 @@ def test_distributed_search_8way_matches_single():
             all_docs.extend(docs)
         full = np.concatenate(all_data)
 
-        from repro import compat
-        mesh = compat.make_mesh((8,), ("data",))
+        from repro.launch.mesh import make_host_mesh
+        mesh = make_host_mesh((8,), ("data",))
         fn = distributed_search_fn(mesh, L=32, k=10)
         stack = lambda f: jnp.stack([f(s) for s in shards])
         args = (
@@ -82,7 +82,7 @@ def test_sharded_train_step_8way_matches_single_device():
     res = _run_subprocess(textwrap.dedent("""
         import json
         import numpy as np, jax, jax.numpy as jnp
-        from repro import compat
+        from repro.launch.mesh import make_host_mesh
         from repro.configs import get_smoke_config
         from repro.configs.shapes import ShapeSpec, input_specs
         from repro.models import steps as steps_mod
@@ -96,7 +96,7 @@ def test_sharded_train_step_8way_matches_single_device():
                                        jnp.int32)}
         losses = {}
         for ms, ax in (((1, 1), ("data", "model")), ((2, 4), ("data", "model"))):
-            mesh = compat.make_mesh(ms, ax)
+            mesh = make_host_mesh(ms, ax)
             b = steps_mod.make_train_step(cfg, mesh, shapes,
                                           OptConfig(lr=1e-3, total_steps=10))
             st = b.init()
@@ -127,8 +127,8 @@ def test_decode_step_sharded_cache():
         tok = jnp.argmax(pl[:, 0], -1).astype(jnp.int32)[:, None]
         ref_logits, _ = M.decode_step(params, cfg, tok, cache, jnp.int32(16))
 
-        from repro import compat
-        mesh = compat.make_mesh((2, 4), ("data", "model"))
+        from repro.launch.mesh import make_host_mesh
+        mesh = make_host_mesh((2, 4), ("data", "model"))
         bundle = steps_mod.make_decode_step(cfg, mesh, batch=8, s_max=2048,
                                             cache_dtype=jnp.float32)
         params_sh = jax.device_put(params, bundle.arg_shardings[0])

@@ -308,11 +308,13 @@ def test_probe_dead_rebuild_matches_live_state():
 # ---------------------------------------------------------------------------
 
 
-def _service(parts=2, replicas=2, n=60, deadline_ms=None):
+def _service(parts=2, replicas=2, n=60, deadline_ms=None,
+             dispatch_mode="serial"):
     svc = VectorCollectionService(
         dim=DIM, graph=_graph(160), max_vectors_per_partition=200,
         initial_partitions=parts, replicas=replicas,
-        engine_cfg=EngineConfig(max_batch=4, default_deadline_ms=deadline_ms),
+        engine_cfg=EngineConfig(max_batch=4, default_deadline_ms=deadline_ms,
+                                dispatch_mode=dispatch_mode),
     )
     rng = np.random.RandomState(9)
     data = rng.randn(n, DIM).astype(np.float32)
@@ -415,3 +417,53 @@ def test_degraded_exact_scan():
         rep.alive = False
     r = svc.query(VectorQuery(vector=data[5], k=5, exact=True))
     assert not r.complete and "+degraded[" in r.plan
+
+
+@pytest.mark.parametrize("plan", ["graph", "filtered", "exact", "spmd"])
+@pytest.mark.parametrize("fault", ["device", "partition"])
+def test_device_fault_propagates_partition_fault_degrades(monkeypatch, plan,
+                                                          fault):
+    """A device fault (``JaxRuntimeError``: a compile failure, HBM
+    exhausted) inside one partition's search is raised to the caller by
+    every fan-out handler; an ordinary partition fault still degrades the
+    answer to the survivors."""
+    import jax
+
+    from repro.serve import vector_engine
+
+    svc, data = _service(parts=2, replicas=2,
+                         dispatch_mode="spmd" if plan == "spmd" else "serial")
+    part = svc.collection.partitions[0]
+    err = (jax.errors.JaxRuntimeError("RESOURCE_EXHAUSTED: injected")
+           if fault == "device" else RuntimeError("injected"))
+
+    def boom(*_a, **_kw):
+        raise err
+
+    if plan == "exact":
+        real = vector_engine.fmod.brute_force
+        calls = []
+
+        def first_partition_fails(*a, **kw):  # partition 0 scans first
+            calls.append(1)
+            if len(calls) == 1:
+                raise err
+            return real(*a, **kw)
+
+        first_partition_fails._cache_size = real._cache_size  # telemetry
+        monkeypatch.setattr(vector_engine.fmod, "brute_force",
+                            first_partition_fails)
+    elif plan == "filtered":
+        monkeypatch.setattr(part, "filtered_search_batch", boom)
+    else:
+        monkeypatch.setattr(part, "search_batch", boom)
+        if plan == "spmd":  # unbuilt graphs take the spmd host fallback
+            monkeypatch.setattr(part.index, "_graph_built", False)
+    q = VectorQuery(vector=data[6], k=5, exact=plan == "exact",
+                    filter=F.eq("cat", 1) if plan == "filtered" else None)
+    if fault == "device":
+        with pytest.raises(jax.errors.JaxRuntimeError):
+            svc.query(q)
+    else:
+        r = svc.query(q)
+        assert not r.complete and f"+degraded[{part.pid}]" in r.plan

@@ -59,11 +59,11 @@ def test_elastic_remesh_restore(tmp_path):
     """Save under one sharding, restore under another mesh shape."""
     from jax.sharding import NamedSharding, PartitionSpec as P
 
-    from repro import compat
+    from repro.launch.mesh import make_host_mesh
     d = str(tmp_path / "el")
     tree = {"w": np.arange(16, dtype=np.float32).reshape(4, 4)}
     ckpt.save(d, 1, tree, extra={})
-    mesh = compat.make_mesh((1,), ("data",))
+    mesh = make_host_mesh((1,), ("data",))
     sh = {"w": NamedSharding(mesh, P("data", None))}
     restored, _ = ckpt.restore(d, tree, shardings=sh)
     np.testing.assert_array_equal(np.asarray(restored["w"]), tree["w"])
