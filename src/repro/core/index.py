@@ -37,6 +37,7 @@ from . import paginate as pgmod
 from . import pq as pqmod
 from . import search as smod
 from .providers import ArrayProviderSet, Context, ProviderSet
+from .spans import span
 
 
 # backup-queue capacity for paginated search: one value service-wide, so
@@ -143,7 +144,8 @@ class DiskANNIndex:
         for start in range(0, len(doc_ids), self.cfg.batch_size):
             ids = list(doc_ids[start : start + self.cfg.batch_size])
             vecs = vectors[start : start + self.cfg.batch_size]
-            self._insert_batch(ids, vecs, stats)
+            with span("write.insert", docs=len(ids)):
+                self._insert_batch(ids, vecs, stats)
         return stats
 
     def _alloc(self, n: int) -> np.ndarray:
@@ -187,7 +189,9 @@ class DiskANNIndex:
             return
 
         # quantized term inline with the document write (§3.4)
-        codes = np.asarray(pqmod.encode(self.schemas[-1], jnp.asarray(vecs)))
+        with span("write.encode"):
+            codes = np.asarray(pqmod.encode(self.schemas[-1],
+                                            jnp.asarray(vecs)))
         ver = np.full((len(slots),), len(self.schemas) - 1, np.uint8)
         self.pv.set_quant(self.ctx, slots, codes, ver)
         self.pv.set_live(self.ctx, slots, True)
@@ -257,28 +261,39 @@ class DiskANNIndex:
         return len(orphans)
 
     def _graph_insert(self, slots: np.ndarray, vecs: np.ndarray, stats: QueryStats):
-        """Mini-batch graph update (Alg 5): jitted search+prune, then one
-        consolidated reverse-edge append per touched node."""
+        """Mini-batch graph update (Alg 5): jitted search+prune
+        (``write.graph_insert``), then one consolidated reverse-edge append
+        per touched node and the re-prune of rows that overflow
+        (``write.prune``)."""
         cfg = self.cfg
         n = len(slots)
-        neighbors, codes, versions, live = self.pv.materialize_graph(self.ctx)
-        # padded to a batch bucket (padding lanes repeat row 0 and are
-        # dropped): the ramp-up build's odd batch sizes share compiles
-        padded = jnp.asarray(smod.pad_batch_np(vecs, smod.next_bucket(n)))
-        cand_ids, _cand_d, istats = imod.insert_candidates(
-            neighbors, codes, versions, live, self._codebook_stack(),
-            padded, jnp.int32(self.medoid),
-            L_build=cfg.L_build, metric=cfg.metric,
-        )
-        nbrs = np.asarray(
-            imod.prune_batch(
-                codes, versions, self._codebook_stack(), padded,
-                cand_ids, R=cfg.R, alpha=cfg.alpha, metric=cfg.metric,
+        with span("write.graph_insert"):
+            neighbors, codes, versions, live = self.pv.materialize_graph(
+                self.ctx)
+            # padded to a batch bucket (padding lanes repeat row 0 and are
+            # dropped): the ramp-up build's odd batch sizes share compiles
+            padded = jnp.asarray(smod.pad_batch_np(vecs, smod.next_bucket(n)))
+            cand_ids, _cand_d, istats = imod.insert_candidates(
+                neighbors, codes, versions, live, self._codebook_stack(),
+                padded, jnp.int32(self.medoid),
+                L_build=cfg.L_build, metric=cfg.metric,
             )
-        )[:n]  # (B, R)
-        stats.hops += float(np.asarray(istats.hops)[:n].sum())
-        stats.cmps += float(np.asarray(istats.cmps)[:n].sum())
+            nbrs = np.asarray(
+                imod.prune_batch(
+                    codes, versions, self._codebook_stack(), padded,
+                    cand_ids, R=cfg.R, alpha=cfg.alpha, metric=cfg.metric,
+                )
+            )[:n]  # (B, R)
+            stats.hops += float(np.asarray(istats.hops)[:n].sum())
+            stats.cmps += float(np.asarray(istats.cmps)[:n].sum())
+        with span("write.prune"):
+            self._link_rows(slots, nbrs, codes, versions)
 
+    def _link_rows(self, slots: np.ndarray, nbrs: np.ndarray,
+                   codes: jax.Array, versions: jax.Array):
+        """Write the new rows, append their reverse edges, and re-prune
+        every row that overflows."""
+        cfg = self.cfg
         rows = np.full((len(slots), cfg.R_slack), -1, np.int32)
         rows[:, : cfg.R] = nbrs
         self.pv.set_neighbors(self.ctx, slots, rows)
@@ -390,34 +405,41 @@ class DiskANNIndex:
     # deletion (Alg 6) + background consolidation
     # ------------------------------------------------------------------
     def delete(self, doc_ids: Sequence[int], policy: str = "inplace"):
-        cfg = self.cfg
         for d in doc_ids:
             slot = self.doc_to_slot.pop(int(d), None)
             if slot is None:
                 continue
-            self.slot_to_doc[slot] = -1
-            self.pv.set_live(self.ctx, np.asarray([slot]), False)
-            if policy == "inplace" and self._graph_built:
-                neighbors, _, _, live, _ = self.pv.materialize(self.ctx)
+            with span("write.delete", docs=1):
+                self._delete_slot(slot, policy)
+
+    def _delete_slot(self, slot: int, policy: str):
+        cfg = self.cfg
+        self.slot_to_doc[slot] = -1
+        self.pv.set_live(self.ctx, np.asarray([slot]), False)
+        if policy == "inplace" and self._graph_built:
+            neighbors, _, _, live, _ = self.pv.materialize(self.ctx)
+            with span("write.readback"):
                 old_nb = np.array(neighbors)  # copy: kernel donates its input
+            with span("write.repair"):
                 # every slot decoded (one shape for any count), unused
                 # slots zeroed
                 decoded = jnp.where(
                     (jnp.arange(cfg.capacity) < self.count)[:, None],
                     self._decoded(np.arange(cfg.capacity)), 0.0)
-                new_nb = dmod.inplace_delete(
+                new_nb = np.asarray(dmod.inplace_delete(
                     neighbors, live, decoded,
                     jnp.int32(slot),
                     R=cfg.R, R_slack=cfg.R_slack, alpha=cfg.alpha,
                     c_replace=cfg.c_replace, metric=cfg.metric,
+                ))
+            with span("write.diff"):
+                self._write_neighbor_diff(old_nb, new_nb)
+        if slot == self.medoid and self.num_live:
+            self.medoid = int(
+                g.compute_medoid(
+                    jnp.asarray(self.pv.vectors), jnp.asarray(self.pv.live)
                 )
-                self._write_neighbor_diff(old_nb, np.asarray(new_nb))
-            if slot == self.medoid and self.num_live:
-                self.medoid = int(
-                    g.compute_medoid(
-                        jnp.asarray(self.pv.vectors), jnp.asarray(self.pv.live)
-                    )
-                )
+            )
 
     def recompute_medoid(self):
         """Start-point maintenance (FreshDiskANN practice): after heavy
@@ -473,19 +495,29 @@ class DiskANNIndex:
         signatures; outputs and stats are sliced back to the true batch.
         ``beam_width`` overrides the config's W (frontier nodes expanded
         per round); None → ``cfg.beam_width``.
+
+        Spans: ``search.prepare`` (pad, device arrays, LUTs),
+        ``search.graph`` (to the beam's read back), ``search.rerank`` (tier
+        touch and launch), ``search.finish`` (stats and answers read back,
+        slot → doc id).
         """
         W = int(beam_width or self.cfg.beam_width)
         queries = np.asarray(queries, np.float32)
         B = len(queries)
-        if pad_to_bucket:
-            queries = smod.pad_batch_np(queries, smod.next_bucket(B, batch_buckets))
         L = L or self.cfg.L_search
         stats = QueryStats()
         kprime = max(k, int(round(rerank_multiplier * k)))
+        with span("search.prepare"):
+            if pad_to_bucket:
+                queries = smod.pad_batch_np(
+                    queries, smod.next_bucket(B, batch_buckets))
+            neighbors, codes, versions, live, vectors = self.pv.materialize(
+                self.ctx)
+            if self._graph_built:
+                luts = self._luts(queries)
 
         if not self._graph_built:
             stats.plan = "brute_force"
-            neighbors, codes, versions, live, vectors = self.pv.materialize(self.ctx)
             ids, dists = fmod.brute_force(
                 jnp.asarray(queries), vectors, live, k=k, metric=self.cfg.metric
             )
@@ -501,31 +533,36 @@ class DiskANNIndex:
                 stats,
             )
 
-        neighbors, codes, versions, live, vectors = self.pv.materialize(self.ctx)
-        luts = self._luts(queries)
         L_eff = max(L, kprime)
-        # queries are already bucket-padded above when pad_to_bucket is set,
-        # so the wrapper's own pad is a no-op then; it still normalizes any
-        # direct unpadded call onto the same static signatures
-        res = smod.bucketed_batch_greedy_search(
-            neighbors, codes, versions, live, luts, jnp.int32(self.medoid),
-            L=L_eff, batch_buckets=batch_buckets, beam_width=W,
-        )
-        # final rerank is the ONLY stage that reads full-precision
-        # vectors: pin the candidate pages (they must not be evicted
-        # mid-rerank), fetch misses, release after
-        pinned = self._touch_tier(
-            np.asarray(res.beam_ids)[:B, :kprime], stats, B, pin=True)
-        ids, dists = fmod.rerank(
-            jnp.asarray(queries), res.beam_ids[:, :kprime], vectors,
-            k=k, metric=self.cfg.metric,
-        )
-        self._unpin_tier(pinned)
-        stats.hops = float(np.asarray(res.n_hops)[:B].mean())
-        stats.cmps = float(np.asarray(res.n_cmps)[:B].mean())
-        stats.expansions = float(np.asarray(res.n_exp)[:B].mean())
-        stats.full_reads = float(kprime)
-        return self._to_doc_ids(np.asarray(ids))[:B], np.asarray(dists)[:B], stats
+        with span("search.graph", W=W, L=L_eff):
+            # queries are already bucket-padded above when pad_to_bucket is
+            # set, so the wrapper's own pad is a no-op then; it still
+            # normalizes any direct unpadded call onto the same static
+            # signatures
+            res = smod.bucketed_batch_greedy_search(
+                neighbors, codes, versions, live, luts,
+                jnp.int32(self.medoid),
+                L=L_eff, batch_buckets=batch_buckets, beam_width=W,
+            )
+            beam = np.asarray(res.beam_ids)
+        with span("search.rerank", kprime=kprime):
+            # final rerank is the ONLY stage that reads full-precision
+            # vectors: pin the candidate pages (they must not be evicted
+            # mid-rerank), fetch misses, release after
+            pinned = self._touch_tier(beam[:B, :kprime], stats, B, pin=True)
+            ids, dists = fmod.rerank(
+                jnp.asarray(queries), res.beam_ids[:, :kprime], vectors,
+                k=k, metric=self.cfg.metric,
+            )
+            self._unpin_tier(pinned)
+        # the stats are read while the rerank runs, its answers last
+        with span("search.finish"):
+            stats.hops = float(np.asarray(res.n_hops)[:B].mean())
+            stats.cmps = float(np.asarray(res.n_cmps)[:B].mean())
+            stats.expansions = float(np.asarray(res.n_exp)[:B].mean())
+            stats.full_reads = float(kprime)
+            return (self._to_doc_ids(np.asarray(ids))[:B],
+                    np.asarray(dists)[:B], stats)
 
     def _to_doc_ids(self, slots: np.ndarray) -> np.ndarray:
         out = np.where(slots >= 0, self.slot_to_doc[np.maximum(slots, 0)], -1)
@@ -557,14 +594,13 @@ class DiskANNIndex:
         recompiles. Outputs and stats slice back to the true batch.
         ``filter_words`` optionally supplies ``doc_filter`` pre-packed in
         the uint32 ``filter_bits`` layout (the predicate compiler's native
-        output), skipping the β-branch re-pack."""
+        output), skipping the β-branch re-pack.
+
+        Spans as in ``search``; the brute-force and Q-Flat plans run
+        under ``search.prepare`` alone."""
         W = int(beam_width or self.cfg.beam_width)
         queries = np.asarray(queries, np.float32)
         B = len(queries)
-        if pad_to_bucket:
-            queries = smod.pad_batch_np(
-                queries, smod.next_bucket(B, batch_buckets)
-            )
         L = L or self.cfg.L_search
         matches = int((doc_filter & self.pv.live).sum())
         stats = QueryStats()
@@ -577,8 +613,16 @@ class DiskANNIndex:
                 mode = "beta"
         stats.plan = mode
         kprime = max(k, int(round(rerank_multiplier * k)))
-        neighbors, codes, versions, live, vectors = self.pv.materialize(self.ctx)
-        fmask = jnp.asarray(doc_filter & self.pv.live)
+        with span("search.prepare"):
+            if pad_to_bucket:
+                queries = smod.pad_batch_np(
+                    queries, smod.next_bucket(B, batch_buckets)
+                )
+            neighbors, codes, versions, live, vectors = self.pv.materialize(
+                self.ctx)
+            fmask = jnp.asarray(doc_filter & self.pv.live)
+            if mode != "brute":
+                luts = self._luts(queries)
 
         if mode == "brute":
             ids, dists = fmod.brute_force(
@@ -591,7 +635,6 @@ class DiskANNIndex:
                     np.asarray(dists)[:B], stats)
 
         if mode == "qflat":
-            luts = self._luts(queries)
             cand, _ = fmod.qflat_scan(
                 luts, codes, versions, fmask, kprime=kprime, metric=self.cfg.metric
             )
@@ -606,42 +649,43 @@ class DiskANNIndex:
             return (self._to_doc_ids(np.asarray(ids))[:B],
                     np.asarray(dists)[:B], stats)
 
-        luts = self._luts(queries)
-        if mode == "post":
-            res = smod.bucketed_batch_greedy_search(
-                neighbors, codes, versions, live, luts, jnp.int32(self.medoid),
-                L=max(L, kprime), batch_buckets=batch_buckets, beam_width=W,
-            )
+        with span("search.graph", W=W, L=max(L, kprime)):
+            if mode == "post":
+                res = smod.bucketed_batch_greedy_search(
+                    neighbors, codes, versions, live, luts,
+                    jnp.int32(self.medoid), L=max(L, kprime),
+                    batch_buckets=batch_buckets, beam_width=W,
+                )
+            else:  # beta (Alg 7)
+                fbits = (filter_words if filter_words is not None
+                         else self._pack_bits(np.asarray(doc_filter)))
+                fb = jnp.asarray(
+                    np.broadcast_to(fbits, (len(queries),) + fbits.shape)
+                )
+                res = smod.bucketed_batch_greedy_search(
+                    neighbors, codes, versions, live, luts,
+                    jnp.int32(self.medoid), L=max(L, kprime),
+                    batch_buckets=batch_buckets,
+                    filter_bits=fb, beta=beta, beam_width=W,
+                )
             beam = np.asarray(res.beam_ids)
             passes = doc_filter[np.maximum(beam, 0)] & (beam >= 0)
             beam = np.where(passes, beam, -1)
-        else:  # beta (Alg 7)
-            fbits = (filter_words if filter_words is not None
-                     else self._pack_bits(np.asarray(doc_filter)))
-            fb = jnp.asarray(
-                np.broadcast_to(fbits, (len(queries),) + fbits.shape)
+        with span("search.rerank", kprime=kprime):
+            pinned = self._touch_tier(beam[:B, : max(L, kprime)], stats, B,
+                                      pin=True)
+            ids, dists = fmod.rerank(
+                jnp.asarray(queries), jnp.asarray(beam[:, : max(L, kprime)]),
+                vectors, k=k, metric=self.cfg.metric,
             )
-            res = smod.bucketed_batch_greedy_search(
-                neighbors, codes, versions, live, luts, jnp.int32(self.medoid),
-                L=max(L, kprime), batch_buckets=batch_buckets,
-                filter_bits=fb, beta=beta, beam_width=W,
-            )
-            beam = np.asarray(res.beam_ids)
-            passes = doc_filter[np.maximum(beam, 0)] & (beam >= 0)
-            beam = np.where(passes, beam, -1)
-        pinned = self._touch_tier(beam[:B, : max(L, kprime)], stats, B,
-                                  pin=True)
-        ids, dists = fmod.rerank(
-            jnp.asarray(queries), jnp.asarray(beam[:, : max(L, kprime)]), vectors,
-            k=k, metric=self.cfg.metric,
-        )
-        self._unpin_tier(pinned)
-        stats.hops = float(np.asarray(res.n_hops)[:B].mean())
-        stats.cmps = float(np.asarray(res.n_cmps)[:B].mean())
-        stats.expansions = float(np.asarray(res.n_exp)[:B].mean())
-        stats.full_reads = float(kprime)
-        return (self._to_doc_ids(np.asarray(ids))[:B],
-                np.asarray(dists)[:B], stats)
+            self._unpin_tier(pinned)
+        with span("search.finish"):
+            stats.hops = float(np.asarray(res.n_hops)[:B].mean())
+            stats.cmps = float(np.asarray(res.n_cmps)[:B].mean())
+            stats.expansions = float(np.asarray(res.n_exp)[:B].mean())
+            stats.full_reads = float(kprime)
+            return (self._to_doc_ids(np.asarray(ids))[:B],
+                    np.asarray(dists)[:B], stats)
 
     @staticmethod
     def _pack_bits(mask: np.ndarray) -> np.ndarray:

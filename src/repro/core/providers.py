@@ -29,6 +29,8 @@ from typing import Optional, Protocol
 import jax.numpy as jnp
 import numpy as np
 
+from .spans import span
+
 
 @dataclasses.dataclass(frozen=True)
 class Context:
@@ -77,6 +79,9 @@ class ArrayProviderSet:
         self.pages = PagedVectorStore(capacity, dim)
         self._cache: dict = {}  # field name -> jnp materialization
         self.write_count = 0
+        # host-to-device copies made by _device: arrays and bytes
+        self.uploads = 0
+        self.upload_bytes = 0
 
     def barrier(self, name: str) -> None:
         """Named crash-barrier hook; no-op without an attached FaultPlan
@@ -94,7 +99,11 @@ class ArrayProviderSet:
     def _device(self, name: str):
         arr = self._cache.get(name)
         if arr is None:
-            arr = self._cache[name] = jnp.asarray(getattr(self, name))
+            host = getattr(self, name)
+            with span("store.upload", field=name, bytes=host.nbytes):
+                arr = self._cache[name] = jnp.asarray(host)
+            self.uploads += 1
+            self.upload_bytes += host.nbytes
         return arr
 
     def materialize(self, ctx: Context = Context()):

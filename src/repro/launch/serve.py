@@ -48,11 +48,16 @@ def main(argv=None):
                          "values; adaptive closes the loop on the "
                          "observability rollups (serve/policy.py)")
     ap.add_argument("--trace-out", default=None, metavar="PATH",
-                    help="dump retained request traces as JSON lines "
-                         "(flight recorder + anomaly ring)")
+                    help="dump the retained trace records as JSON lines "
+                         "(flight recorder + anomaly ring), stamped on the "
+                         "host clock: queries, pages, write requests, and "
+                         "one `batch` record per micro-batch holding its "
+                         "measured serve.*/search.*/store.* spans")
     ap.add_argument("--metrics-out", default=None, metavar="PATH",
                     help="dump the labeled metrics registry in Prometheus "
-                         "text exposition format")
+                         "text exposition format, with the program's "
+                         "per-span totals (serve_span_seconds_total, "
+                         "serve_span_count, serve_span_max_seconds)")
     args = ap.parse_args(argv)
     enable_compile_cache()
 
@@ -103,12 +108,17 @@ def main(argv=None):
           f"({vt['resident_pages']}/{vt['capacity_pages']} pages, "
           f"hit rate {vt['hit_rate']:.2f})")
 
+    print(f"host path: {snap['answers']} answers held "
+          f"{snap['answer_hold_ns'] * 1e-6:.3f} ms in all behind "
+          f"interleaved writes; {snap['uploads']} array uploads, "
+          f"{snap['upload_bytes'] / 2**20:.1f} MiB")
     if args.trace_out:
         n = svc.engine.tracer.dump_jsonl(args.trace_out)
         print(f"wrote {n} trace records to {args.trace_out}")
     if args.metrics_out:
         with open(args.metrics_out, "w") as f:
-            f.write(svc.engine.obs.to_prometheus_text())
+            f.write(svc.engine.obs.to_prometheus_text(
+                spans=svc.engine.tracer.span_totals()))
         print(f"wrote metrics exposition to {args.metrics_out}")
 
 

@@ -41,6 +41,7 @@ from ..core import paginate as pgmod
 from ..core import pq as pqmod
 from ..core import search as smod
 from ..core.index import QueryStats
+from ..core.spans import span
 from ..store.faults import CrashError
 from ..store.props import words_to_mask
 from ..store.ru import counters_for_latency, counters_for_ru
@@ -62,11 +63,13 @@ def merge_topk(
     ids_list: Sequence[np.ndarray], dists_list: Sequence[np.ndarray], k: int
 ) -> tuple[np.ndarray, np.ndarray]:
     """Merge per-partition (B, k_i) partial results into global (B, k)."""
-    ids = np.concatenate(ids_list, axis=1)
-    dists = np.concatenate(dists_list, axis=1)
-    dists = np.where(ids >= 0, dists, np.inf)
-    order = np.argsort(dists, axis=1)[:, :k]
-    return np.take_along_axis(ids, order, 1), np.take_along_axis(dists, order, 1)
+    with span("search.merge"):
+        ids = np.concatenate(ids_list, axis=1)
+        dists = np.concatenate(dists_list, axis=1)
+        dists = np.where(ids >= 0, dists, np.inf)
+        order = np.argsort(dists, axis=1)[:, :k]
+        return (np.take_along_axis(ids, order, 1),
+                np.take_along_axis(dists, order, 1))
 
 
 def fanout_search(
@@ -151,7 +154,8 @@ def batched_fanout_search(
             failed.append((int(p.pid), "replica set down"))
             continue
         try:
-            ids, dists, ru, stats = p.search_batch(queries, k, L, **kw)
+            with span("search.partition", pid=int(p.pid)):
+                ids, dists, ru, stats = p.search_batch(queries, k, L, **kw)
         except (CrashError, jax.errors.JaxRuntimeError):
             # an injected process kill, or a device fault (a compile
             # failure, HBM exhausted), is not a partition fault
@@ -254,10 +258,11 @@ def batched_filtered_fanout_search(
                 compile_ru += nreads * p.providers.meter.cfg.ru_per_prop_read
                 answered += 1
                 continue
-            ids, dists, ru, stats = p.filtered_search_batch(
-                queries, k, mask, L=L, term_reads=nreads,
-                filter_words=words, **kw
-            )
+            with span("search.partition", pid=int(p.pid)):
+                ids, dists, ru, stats = p.filtered_search_batch(
+                    queries, k, mask, L=L, term_reads=nreads,
+                    filter_words=words, **kw
+                )
         except (CrashError, jax.errors.JaxRuntimeError):
             # an injected process kill, or a device fault (a compile
             # failure, HBM exhausted), is not a partition fault
@@ -774,7 +779,9 @@ class SpmdFanout:
             if W is not None:
                 kw["beam_width"] = W
             try:
-                ids, dists, ru, stats = p.search_batch(queries, k, L, **kw)
+                with span("search.partition", pid=int(p.pid)):
+                    ids, dists, ru, stats = p.search_batch(queries, k, L,
+                                                           **kw)
             except (CrashError, jax.errors.JaxRuntimeError):
                 # an injected process kill, or a device fault (a compile
                 # failure, HBM exhausted), is not a partition fault

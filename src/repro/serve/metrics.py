@@ -163,6 +163,11 @@ class EngineMetrics:
     # batching shows up here as mean_hops dropping ~W×
     hops_weighted: float = 0.0
     hops_lanes: int = 0
+    # answers stored by pump() (200s and 408s), and the host nanoseconds
+    # they waited, summed over answers, behind the writes the same call
+    # applied after them (the interleaved ingest holding answers back)
+    answers: int = 0
+    answer_hold_ns: int = 0
     # dispatch-plane telemetry: hedged duplicates bill RU; lane faults
     # and recoveries mirror the executor's health machine
     hedges: int = 0
@@ -236,6 +241,8 @@ class EngineMetrics:
             hedges_won=self.hedges_won,
             hedge_ru_total=self.hedge_ru_total,
             mean_hops=self.hops_weighted / max(self.hops_lanes, 1),
+            answers=self.answers,
+            answer_hold_ns=self.answer_hold_ns,
             mean_occupancy=self.occupancy.mean(),
             pad_fraction=self.lanes_padded / max(self.lanes_total, 1),
             jit_cache_size=(self.jit_cache_trajectory[-1]

@@ -26,7 +26,11 @@ Conservation contracts (asserted in tests/test_observability.py):
 
 ``to_prometheus_text`` renders the standard text exposition format
 (counters, and summary-style quantiles for histograms) for the
-``launch/serve.py --metrics-out`` exporter.
+``launch/serve.py --metrics-out`` exporter; given the program's span
+totals (``Tracer.span_totals``) it appends them as
+``serve_span_seconds_total{span}``, ``serve_span_count{span}`` and
+``serve_span_max_seconds{span}`` — measured host time, unlike the
+SimClock ``serve_latency_ms``/``serve_stage_ms``.
 """
 from __future__ import annotations
 
@@ -179,9 +183,11 @@ class MetricsRegistry:
             out[name] = fam_out
         return out
 
-    def to_prometheus_text(self) -> str:
+    def to_prometheus_text(self, spans: Optional[dict] = None) -> str:
         """Standard Prometheus text exposition. Histograms render as
-        summaries (quantile series + ``_sum``/``_count``)."""
+        summaries (quantile series + ``_sum``/``_count``); ``spans``, a
+        {name: (count, total ns, largest ns)} map, renders as the three
+        ``serve_span_*`` families."""
         lines = []
         for name, fam in sorted(self._families.items()):
             if fam.kind == "counter":
@@ -200,7 +206,18 @@ class MetricsRegistry:
                     base = _fmt_labels(fam.labelnames, key)
                     lines.append(f"{name}_sum{base} {_fmt_num(h.sum)}")
                     lines.append(f"{name}_count{base} {h.count}")
+        for family, kind, col, scale in _SPAN_FAMILIES if spans else ():
+            lines.append(f"# TYPE {family} {kind}")
+            for name, row in sorted(spans.items()):
+                lines.append(f"{family}{_fmt_labels(('span',), (name,))} "
+                             f"{_fmt_num(row[col] * scale)}")
         return "\n".join(lines) + ("\n" if lines else "")
+
+
+# (family, type, column of the (count, total ns, max ns) row, scale)
+_SPAN_FAMILIES = (("serve_span_seconds_total", "counter", 1, 1e-9),
+                  ("serve_span_count", "counter", 0, 1),
+                  ("serve_span_max_seconds", "gauge", 2, 1e-9))
 
 
 def _escape(v: str) -> str:

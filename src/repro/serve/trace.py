@@ -1,58 +1,55 @@
-"""Request-lifecycle tracing on simulated time — the serving sensor layer.
+"""Request records and measured spans on the host clock: the serving
+engine's trace plane.
 
-The paper's headline claims are *observability* claims: <20 ms query
-latency, stable recall over updates, ~43×/12× lower query cost (§4,
-Figs 10-13). Verifying them per request needs a stage-level decomposition
-of where each millisecond and each RU goes — admission, queue wait,
-batch formation, lane dispatch (hedge duplicates, fault retries),
-per-partition fan-out, merge. This module provides that decomposition:
+Every time here is measured on the host clock, in seconds since
+``repro.core.spans.EPOCH_NS``; none is the engine's SimClock, which stays
+the latency *model* (``ServeResponse.latency_ms``, ``EngineMetrics``).
 
-  * ``Span`` / ``Trace`` — one trace per query / page / ingest op, with
-    child spans per lifecycle stage. All timestamps are **SimClock
-    seconds** (the engine's deterministic simulated timeline), so traces
-    are bit-reproducible offline and stage durations reconcile *exactly*
-    with the latency the engine records: the root-level stage spans of a
-    served request tile its [arrival, completion] interval, so
-    ``sum(root span durations) == latency_ms`` (asserted by
-    ``validate_trace_record`` and the tier-1 tests). Child spans under
-    ``lane`` model the *parallel* structure (per-partition fan-out, the
-    hedge duplicate) and deliberately overlap.
+  * ``repro.core.spans.span`` is the one timing primitive. A span lands
+    in the profiler's trace, on the device trace's clock, while a
+    profiler records; it adds to always-on per-name totals
+    (``Tracer.span_totals``, exported as ``serve_span_*`` by
+    ``MetricsRegistry.to_prometheus_text``); and while this tracer is
+    enabled the engine collects it, with its parent, into the record
+    being built.
+
+  * ``Trace`` / ``Span`` — one record per query, page, micro-batch,
+    write request or policy action. The root spans of a query tile
+    [submit → answer stored], so their durations sum to the record's
+    host-clock ``latency_ms`` (``validate_trace_record`` checks it for
+    every 200 and 408):
+
+      admission   point event at submit: the RU reservation
+      queue       [submit → its micro-batch leaves the queue]
+      batch_form  point event then: batch size, bucket, plan, beam width
+      lane        [leaves the queue → answer stored]
+      deadline    point event (408 only): the deadline expired queued
+
+    Point events under ``lane``: ``hedge`` and ``retry`` (the replica
+    lanes are simulated, so their durations are not time), a partition a
+    degraded fan-out lost, a page's per-partition fetch (``partition``).
+    The measured spans of one micro-batch — ``serve.dispatch`` and what it
+    runs: ``search.partition``, ``search.prepare``, ``search.graph``,
+    ``search.rerank``, ``search.finish``, ``search.merge``,
+    ``store.upload``, ``serve.respond`` — are recorded once, in a record
+    of kind ``batch``; each query record names it by ``batch_id``. A
+    write request's record (kind ``ingest``) holds ``serve.ingest`` and
+    its ``write.*`` and ``store.upload`` spans. A program span's stage is
+    its layer, the part of its name before the dot.
 
   * ``Tracer`` — the factory the engine owns. ``enabled=False`` makes
-    ``begin`` return ``None`` and every hot path guards on that, so a
-    disabled tracer costs one attribute read per request — nothing is
-    allocated, nothing is retained.
+    ``begin`` return ``None``; every emission site guards on that, so a
+    disabled tracer allocates and retains nothing (the span totals stay
+    on: they are per name, not per call).
 
-  * ``FlightRecorder`` — a bounded ring buffer of recent trace records
-    plus a *separate* bounded ring for anomalous traces (throttles,
-    faults, hedges, SLO violations), so a burst of healthy traffic can
-    never evict the interesting evidence.
+  * ``FlightRecorder`` — a bounded ring of recent records plus a
+    separate bounded ring for anomalous ones (throttles, faults, hedges,
+    SLO violations), so a burst of healthy traffic never evicts the
+    interesting evidence.
 
   * Exporters — ``Tracer.dump_jsonl`` writes the retained records as
-    JSON lines; ``validate_trace_record`` is the schema contract the
-    benchmark gate re-checks on every emitted line.
-
-Stage taxonomy (``STAGES``):
-
-  admission   point event: the RU-governance decision (reserved estimate)
-  queue       [arrival → lane start]: batching + lane queue wait
-  batch_form  point event at dispatch: batch size / bucket / plan
-  lane        [lane start → completion]: the dispatch-plane service
-  partition   child of lane: one span per physical partition searched,
-              carrying that partition's RU and the search counters the
-              RU/latency split is computed from (hops / expansions /
-              cmps — see ``store.ru.counters_for_ru`` /
-              ``counters_for_latency``)
-  hedge       child of lane: the straggler duplicate (RU billed in full)
-  retry       child of lane: a lane fault burned before the work ran
-  merge       child of lane: host-side merge / dispatch overhead
-  ingest      root span of an ingest mini-batch trace
-  deadline    point event: the request's deadline expired while it was
-              still queued; the engine abandoned it (status 408) and
-              refunded the admission reservation
-  policy      point event: a control-plane action (serve/policy.py) —
-              a topology split / replica scale-out with the signals
-              that triggered it, so scaling is attributable in traces
+    JSON lines (``launch/serve.py --trace-out``);
+    ``validate_trace_record`` is the schema contract.
 """
 from __future__ import annotations
 
@@ -61,12 +58,13 @@ import json
 from collections import deque
 from typing import Any, Optional
 
-from .metrics import SimClock
+from ..core.spans import EPOCH_NS, now_s, totals
 
-STAGES = ("admission", "queue", "batch_form", "lane", "partition", "hedge",
-          "retry", "merge", "ingest", "deadline", "policy", "rerank")
+PROGRAM_STAGES = ("serve", "search", "write", "store")
+STAGES = ("admission", "queue", "batch_form", "lane", "deadline",
+          "partition", "hedge", "retry", "policy") + PROGRAM_STAGES
 
-TRACE_KINDS = ("query", "page", "ingest", "policy")
+TRACE_KINDS = ("query", "page", "batch", "ingest", "policy")
 
 # anomaly tags the flight recorder always captures
 ANOMALY_THROTTLE = "throttle"
@@ -79,7 +77,7 @@ ANOMALY_DEGRADED = "degraded"
 
 @dataclasses.dataclass
 class Span:
-    """One lifecycle stage of one request, on SimClock time."""
+    """One stage of one record, in host-clock seconds."""
 
     name: str
     stage: str
@@ -95,10 +93,10 @@ class Span:
 
 @dataclasses.dataclass
 class Trace:
-    """One request's lifecycle: a flat span list with parent links."""
+    """One record: a flat span list with parent links."""
 
     trace_id: int
-    kind: str  # query | page | ingest
+    kind: str  # query | page | batch | ingest | policy
     tenant: Any
     rid: int
     t0_s: float = 0.0
@@ -106,6 +104,7 @@ class Trace:
     status: int = 0
     ru: float = 0.0
     latency_ms: float = 0.0
+    batch_id: int = -1  # a query's micro-batch record, -1 for none
     anomalies: list = dataclasses.field(default_factory=list)
     spans: list = dataclasses.field(default_factory=list)
 
@@ -116,11 +115,19 @@ class Trace:
                                parent, attrs))
         return len(self.spans) - 1
 
+    def add_program_spans(self, records: list) -> None:
+        """Append the spans ``repro.core.spans.collect`` recorded, parent
+        links kept."""
+        base = len(self.spans)
+        for name, t0, t1, parent, meta in records:
+            self.spans.append(Span(
+                name, name.partition(".")[0], (t0 - EPOCH_NS) * 1e-9,
+                (t1 - EPOCH_NS) * 1e-9,
+                parent + base if parent >= 0 else -1, dict(meta)))
+
     def stage_totals(self) -> dict:
-        """Root-span duration per stage (ms). Root spans are sequential —
-        they tile [t0, t1] — so their sum reconciles with latency_ms;
-        children (partition fan-out, hedge) model parallel structure and
-        are excluded."""
+        """Root-span duration per stage (ms). Root spans tile [t0, t1], so
+        their sum reconciles with latency_ms; children are excluded."""
         out: dict[str, float] = {}
         for s in self.spans:
             if s.parent == -1:
@@ -142,6 +149,7 @@ class Trace:
             t1_s=self.t1_s,
             latency_ms=self.latency_ms,
             ru=self.ru,
+            batch_id=self.batch_id,
             anomalies=list(self.anomalies),
             spans=[
                 dict(name=s.name, stage=s.stage, t0_s=s.t0_s, t1_s=s.t1_s,
@@ -193,7 +201,7 @@ class FlightRecorder:
 
 
 class Tracer:
-    """The engine's trace factory on the shared SimClock.
+    """The engine's record factory, on the host clock.
 
     Zero-overhead when disabled: ``begin`` returns ``None`` and callers
     guard span emission on that — no allocation, no retention. When
@@ -201,9 +209,8 @@ class Tracer:
     SLO) and hands the record to the flight recorder.
     """
 
-    def __init__(self, clock: SimClock, enabled: bool = True,
-                 capacity: int = 256, slo_ms: Optional[float] = None):
-        self.clock = clock
+    def __init__(self, enabled: bool = True, capacity: int = 256,
+                 slo_ms: Optional[float] = None):
         self.enabled = bool(enabled)
         self.slo_ms = slo_ms
         self._capacity = int(capacity)
@@ -213,7 +220,8 @@ class Tracer:
         self._next_id = 0
 
     def reset(self):
-        """Fresh recorder + counters (benchmark warmup boundary)."""
+        """Fresh recorder + counters (benchmark warmup boundary). The span
+        totals are process-wide and monotone; they do not reset."""
         self.recorder = FlightRecorder(self._capacity)
         self.started = 0
         self.finished = 0
@@ -226,7 +234,7 @@ class Tracer:
         tid = self._next_id
         self._next_id += 1
         return Trace(trace_id=tid, kind=kind, tenant=tenant, rid=rid,
-                     t0_s=self.clock.now())
+                     t0_s=now_s())
 
     def finish(self, tr: Trace, status: int, ru: float, latency_ms: float,
                t0_s: Optional[float] = None, t1_s: Optional[float] = None,
@@ -236,7 +244,7 @@ class Tracer:
         tr.latency_ms = float(latency_ms)
         if t0_s is not None:
             tr.t0_s = float(t0_s)
-        tr.t1_s = float(t1_s) if t1_s is not None else self.clock.now()
+        tr.t1_s = float(t1_s) if t1_s is not None else now_s()
         tags = list(anomalies)
         if status == 429 and ANOMALY_THROTTLE not in tags:
             tags.append(ANOMALY_THROTTLE)
@@ -247,12 +255,28 @@ class Tracer:
             tags.append(ANOMALY_HEDGE)
         if "retry" in stages:
             tags.append(ANOMALY_FAULT)
-        if (self.slo_ms is not None and tr.kind != "ingest"
+        if (self.slo_ms is not None and tr.kind in ("query", "page")
                 and latency_ms > self.slo_ms):
             tags.append(ANOMALY_SLO)
         tr.anomalies = tags
         self.finished += 1
         self.recorder.record(tr)
+
+    def finish_measured(self, tr: Trace, records: list, ru: float):
+        """Finish a ``batch`` or ``ingest`` record from the spans
+        ``repro.core.spans.collect`` recorded: its root span is the
+        record's interval and latency."""
+        tr.add_program_spans(records)
+        root = tr.spans[0]
+        self.finish(tr, status=200, ru=ru, latency_ms=root.dur_ms,
+                    t0_s=root.t0_s, t1_s=root.t1_s)
+
+    @staticmethod
+    def span_totals() -> dict:
+        """Per program span name: (count, total ns, largest ns) since the
+        process started (``repro.core.spans.totals``), whether or not this
+        tracer is enabled."""
+        return totals()
 
     # ------------------------------------------------------------------
     # exporters
@@ -286,7 +310,7 @@ class Tracer:
 _REQUIRED = {
     "trace_id": int, "kind": str, "tenant": str, "rid": int, "status": int,
     "t0_s": (int, float), "t1_s": (int, float),
-    "latency_ms": (int, float), "ru": (int, float),
+    "latency_ms": (int, float), "ru": (int, float), "batch_id": int,
     "anomalies": list, "spans": list,
 }
 
@@ -295,18 +319,19 @@ _SPAN_REQUIRED = {
     "dur_ms": (int, float), "parent": int, "attrs": dict,
 }
 
+_NEST_TOL_S = 1e-9
+
 
 def validate_trace_record(rec: dict) -> None:
     """Raise ``ValueError`` unless ``rec`` is a well-formed trace record.
 
     Beyond structural checks (keys, types, stage taxonomy, parent links),
-    this enforces the cost-attribution contract: for a served (status
-    200) or deadline-abandoned (status 408) request, the root-level
-    stage spans tile the request interval, so their summed duration
-    equals ``latency_ms`` within clock resolution. That is the
-    invariant that makes per-stage dashboards trustworthy — stages can
-    never silently leak time, even for requests that never reached a
-    lane.
+    every child span lies inside its parent's interval (spans are
+    measured, so a child cannot outlast the block it ran in), and for a
+    served (status 200) or deadline-abandoned (status 408) record the
+    root spans tile the record's interval: their summed duration equals
+    ``latency_ms`` within clock resolution, so stages can never silently
+    leak time.
     """
     if not isinstance(rec, dict):
         raise ValueError("trace record must be a dict")
@@ -338,6 +363,12 @@ def validate_trace_record(rec: dict) -> None:
         if not -1 <= s["parent"] < i:
             raise ValueError(f"span {i} parent {s['parent']} must point at "
                              f"an earlier span (or -1)")
+        if s["parent"] >= 0:
+            p = spans[s["parent"]]
+            if (s["t0_s"] < p["t0_s"] - _NEST_TOL_S
+                    or s["t1_s"] > p["t1_s"] + _NEST_TOL_S):
+                raise ValueError(f"span {i} ({s['name']}) lies outside its "
+                                 f"parent {p['name']}")
     if rec["status"] in (200, 408):
         root_ms = sum(s["dur_ms"] for s in spans if s["parent"] == -1)
         tol = 1e-6 + 1e-9 * abs(rec["latency_ms"])

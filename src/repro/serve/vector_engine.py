@@ -40,6 +40,8 @@ from __future__ import annotations
 
 import dataclasses
 from collections import deque
+from contextlib import nullcontext
+from time import perf_counter_ns
 from typing import Any, Callable, Optional, Sequence
 
 import jax
@@ -48,6 +50,7 @@ import numpy as np
 
 from ..core import flat as fmod
 from ..core import search as smod
+from ..core.spans import collect, now_s, span
 from ..partition.fanout import (AllPartitionsFailed, SpmdFanout,
                                 batched_fanout_search,
                                 batched_filtered_fanout_search,
@@ -91,7 +94,7 @@ class EngineConfig:
     beam_width: int = 4  # W-way frontier expansion per search round (§3.2
     #   beamWidth): ~W× fewer sequential rounds on the lockstep hot path
     search_list_multiplier: float = 5.0  # L = multiplier * k when unset
-    dispatch_overhead_ms: float = 0.1  # host-side per-batch overhead
+    dispatch_overhead_ms: float = 0.1  # modelled host cost per batch
     tenant_ru_s: float = 10_000.0  # default per-tenant provisioned budget
     admission_control: bool = True
     admission_estimate_ru: float = 20.0  # prior until an EMA exists
@@ -108,7 +111,7 @@ class EngineConfig:
     lane_reprobe_after_s: float = 5.0  # down-lane re-probe cooldown
     dispatch_seed: int = 0  # lane-plane RNG seed (straggler draws)
     # ---- observability (serve.trace / serve.obs) ----
-    trace: bool = True  # per-request lifecycle traces; off = zero overhead
+    trace: bool = True  # host-clock request/batch records; off = none kept
     flight_recorder: int = 256  # trace records retained (ring + anomaly ring)
     trace_slo_ms: Optional[float] = 50.0  # SLO-violating traces always captured
     # ---- fault tolerance ----
@@ -149,7 +152,10 @@ class ServeRequest:
     # overload is charged to latency even when the engine is running behind.
     arrival_s: float = -1.0
     reserved_ru: float = 0.0  # admission reservation, reconciled at dispatch
-    admit_s: float = -1.0  # when the admission decision was made (trace plane)
+    # host-clock stamps (repro.core.spans.now_s) for the trace plane: at
+    # submit, and when its micro-batch leaves the queue
+    submit_s: float = 0.0
+    dequeued_s: float = 0.0
     # queue-abandonment budget (ms from arrival). None → engine default.
     # A request still queued past its deadline is abandoned with a 408
     # and its reservation refunded; a request already dispatched runs to
@@ -236,7 +242,7 @@ class VectorServeEngine:
         # lifecycle tracer (zero-cost when cfg.trace is off — begin()
         # returns None and every emission site guards on it)
         self.obs = MetricsRegistry()
-        self.tracer = Tracer(self.clock, enabled=cfg.trace,
+        self.tracer = Tracer(enabled=cfg.trace,
                              capacity=cfg.flight_recorder,
                              slo_ms=cfg.trace_slo_ms)
         # control plane (serve.policy): disabled policies short-circuit
@@ -305,15 +311,15 @@ class VectorServeEngine:
     def submit(self, req: ServeRequest) -> Optional[ServeResponse]:
         """Enqueue a query. Returns a 429 response immediately when the
         tenant is over budget, else None (the answer arrives at dispatch)."""
+        req.submit_s = now_s()
         rejected, reserved = self._admit(req.tenant)
         if rejected is not None:
             resp = dataclasses.replace(rejected, rid=req.rid)
             self.responses[req.rid] = resp
             self._note_throttle("query", req.rid, req.tenant,
-                                resp.retry_after_s)
+                                resp.retry_after_s, req.submit_s)
             return resp
         req.reserved_ru = reserved
-        req.admit_s = self.clock.now()
         if req.arrival_s < 0:
             req.arrival_s = self.clock.now()
         dl = req.deadline_ms
@@ -375,8 +381,13 @@ class VectorServeEngine:
         policy every loop iteration opens with a policy tick — the
         beam-width / ingest-yield decision is PER MICRO-BATCH, re-read
         from the rollups as the backlog drains, and a tick may fire a
-        topology action (split / lane scale-out)."""
+        topology action (split / lane scale-out).
+
+        Answers stored by this call wait in ``responses`` until it
+        returns: the host time they wait behind the writes it interleaves
+        adds to ``metrics.answer_hold_ns`` (answers held × write time)."""
         served = 0
+        answers0 = self.metrics.answers
         progressed = True
         while progressed:
             progressed = False
@@ -385,7 +396,11 @@ class VectorServeEngine:
                 batch = reqs[: self.cfg.max_batch]
                 self._dispatch(key, batch)
                 served += len(batch)
-                self._interleave_ingest()
+                t0 = perf_counter_ns()
+                if self._interleave_ingest():
+                    self.metrics.answer_hold_ns += (
+                        (self.metrics.answers - answers0)
+                        * (perf_counter_ns() - t0))
                 progressed = True
                 break  # re-derive groups: the clock moved
         if not served:
@@ -428,6 +443,9 @@ class VectorServeEngine:
     def _dispatch(self, key: tuple, batch: list[ServeRequest]):
         in_batch = set(id(r) for r in batch)
         self.queue = [r for r in self.queue if id(r) not in in_batch]
+        dequeued = now_s()
+        for r in batch:
+            r.dequeued_s = dequeued
         # deadline sweep: a request whose budget expired while it queued is
         # abandoned HERE — before any lane work is spent on it — with its
         # admission reservation refunded (the 408 path)
@@ -461,19 +479,21 @@ class VectorServeEngine:
         rs = self._rs_by_partition.get(id(p))
         return rs is None or bool(rs.healthy())
 
-    def _expire(self, r: ServeRequest, now_s: float):
+    def _expire(self, r: ServeRequest, sim_now: float):
         """Abandon one deadline-expired queued request: refund the
         admission reservation (no work was done on the tenant's dime),
-        answer 408, and emit a trace whose root spans — admission point,
-        queue [arrival → expiry], deadline point — tile the waited
-        interval exactly like a served request's do."""
+        answer 408, and emit a record whose root spans — admission point,
+        queue [submit → dequeued], deadline point — tile the waited host
+        interval exactly like a served request's do. ``sim_now`` is the
+        SimClock time the deadline was checked at (the latency model)."""
         self.tenant_governor(r.tenant).refund(r.reserved_ru)
-        waited_ms = (now_s - r.arrival_s) * 1000.0
+        waited_ms = (sim_now - r.arrival_s) * 1000.0
         assert r.rid not in self.responses
         self.responses[r.rid] = ServeResponse(
             rid=r.rid, status=408, latency_ms=waited_ms, wait_ms=waited_ms,
         )
         self.metrics.queries_deadline += 1
+        self.metrics.answers += 1
         ts = str(r.tenant)
         self.obs.inc("serve_requests_total", tenant=ts, kind="query",
                      status="408")
@@ -481,16 +501,33 @@ class VectorServeEngine:
         tr = self.tracer.begin("query", r.tenant, r.rid)
         if tr is None:
             return
-        tr.span("admission", "admission", r.admit_s, r.admit_s,
+        t0, t1 = r.submit_s, r.dequeued_s
+        tr.span("admission", "admission", t0, t0,
                 reserved_ru=r.reserved_ru, refunded=True)
-        tr.span("queue", "queue", r.arrival_s, now_s)
-        tr.span("deadline", "deadline", now_s, now_s,
+        tr.span("queue", "queue", t0, t1)
+        tr.span("deadline", "deadline", t1, t1,
                 deadline_ms=(r.deadline_s - r.arrival_s) * 1000.0,
                 waited_ms=waited_ms)
-        self.tracer.finish(tr, status=408, ru=0.0, latency_ms=waited_ms,
-                           t0_s=r.arrival_s, t1_s=now_s)
+        self.tracer.finish(tr, status=408, ru=0.0,
+                           latency_ms=(t1 - t0) * 1000.0, t0_s=t0, t1_s=t1)
 
     def _dispatch_chunk(self, key: tuple, batch: list[ServeRequest]):
+        """One micro-batch, inside the ``serve.dispatch`` span. With the
+        tracer enabled, the spans it opens are collected into one record
+        of kind ``batch``, which each query's record names."""
+        B = len(batch)
+        bucket = smod.next_bucket(B, self.cfg.batch_buckets)
+        btr = self.tracer.begin("batch", "engine", -1)
+        with (collect() if btr is not None else nullcontext()) as recs, \
+                span("serve.dispatch", batch=B, bucket=bucket):
+            ru = self._serve_chunk(key, batch, bucket,
+                                   -1 if btr is None else btr.trace_id)
+        if btr is not None:
+            self.tracer.finish_measured(btr, recs, ru)
+
+    def _serve_chunk(self, key: tuple, batch: list[ServeRequest],
+                     bucket: int, batch_id: int) -> float:
+        """Search, settle and answer one micro-batch; returns its RU."""
         shard_key, k, L, exact, _pred_key = key
         predicate = batch[0].predicate  # whole group shares one canonical key
         queries = np.stack([r.vector for r in batch]).astype(np.float32)
@@ -505,7 +542,7 @@ class VectorServeEngine:
             # time is spent, never what runs
             partitions = self._resolve(shard_key)
             if exact:
-                ids, dists, ru_total, service_ms, plan, pspans, failed = \
+                ids, dists, ru_total, service_ms, plan, tier, failed = \
                     self._exact_scan(partitions, queries, k,
                                      predicate=predicate, health=health)
             else:
@@ -534,20 +571,17 @@ class VectorServeEngine:
                     plan = "graph"
                 ru_total = info["ru_total"]
                 service_ms = info["service_latency_ms"]
-                pspans = self._partition_spans(info)
                 failed = list(info.get("failed_partitions", ()))
                 pstats = info["stats_per_partition"]
+                # paged-tier touches, per query, summed over the fan-out
+                tier = (sum(s.tier_hits for s in pstats),
+                        sum(s.tier_misses for s in pstats))
                 if pstats:
                     self.metrics.note_hops(
                         float(np.mean([s.hops for s in pstats])), len(batch)
                     )
-            # degraded fan-out: the survivors answered; record each missing
-            # partition as a zero-duration failure span under the lane
-            for pid, err in failed:
-                pspans.append((0.0, dict(pid=int(pid), failed=True,
-                                         error=str(err), ru=0.0)))
             service_ms += self.cfg.dispatch_overhead_ms
-            return (ids, dists, plan, pspans, failed), service_ms, ru_total
+            return (ids, dists, plan, tier, failed), service_ms, ru_total
 
         try:
             out = self.executor.dispatch(run)
@@ -558,14 +592,10 @@ class VectorServeEngine:
                 self.tenant_governor(r.tenant).refund(r.reserved_ru)
             raise
 
-        ids, dists, plan, pspans, failed = out.payload
+        ids, dists, plan, (tier_h, tier_m), failed = out.payload
         complete = not failed
         if failed:
             plan += "+degraded[" + ",".join(str(p) for p, _ in failed) + "]"
-        # paged-tier accounting (ISSUE 10): per-query hit/miss shares from
-        # the partition stats, surfaced as metrics + rerank child spans
-        tier_h, tier_m = self._tier_totals(pspans)
-        rerank_spans = self._rerank_spans(pspans)
         ru_work = out.ru  # the batch's search work, hedge surcharge apart
         ru_total = out.ru + out.hedge_ru  # hedged duplicates bill in full
         service_ms = (out.end_s - out.start_s) * 1000.0
@@ -573,115 +603,70 @@ class VectorServeEngine:
             self.metrics.note_hedge(out.hedge_won, out.hedge_ru)
 
         B = len(batch)
-        bucket = smod.next_bucket(B, self.cfg.batch_buckets)
         self.metrics.note_batch(B, bucket, service_ms, ru_work,
                                 serving_jit_cache_size())
         ru_q = ru_total / B  # what the client is billed (hedge included)
         work_q = ru_work / B
         hedge_q = out.hedge_ru / B
-        for i, r in enumerate(batch):
-            # start_s includes lane queue wait: under replica dispatch a
-            # batch that finds every lane busy pays that wait in its
-            # latency percentiles, exactly like a real executor pool
-            wait_ms = (out.start_s - r.arrival_s) * 1000.0
-            lat_ms = (out.end_s - r.arrival_s) * 1000.0
-            assert r.rid not in self.responses, (
-                f"rid {r.rid} already answered: one admitted request must "
-                f"produce exactly one response/latency sample (hedge and "
-                f"retry duplicates are lane-plane internals)"
-            )
-            self.responses[r.rid] = ServeResponse(
-                rid=r.rid, status=200, ids=ids[i], dists=dists[i], ru=ru_q,
-                plan=plan, latency_ms=lat_ms, wait_ms=wait_ms, batch_size=B,
-                complete=complete,
-            )
-            self.metrics.queries_ok += 1
-            if not complete:
-                self.metrics.queries_degraded += 1
-                self.obs.inc("serve_degraded_total", tenant=str(r.tenant))
-            self.metrics.latency_ms.observe(lat_ms)
-            self.metrics.wait_ms.observe(wait_ms)
-            self._settle(r.tenant, ru_q, r.reserved_ru)
-            ts = str(r.tenant)
-            self.obs.inc("serve_requests_total", tenant=ts, kind="query",
-                         status="200")
-            self.obs.inc("serve_ru_total", work_q, tenant=ts, op="query")
-            if out.hedge_ru:
-                self.obs.inc("serve_ru_total", hedge_q, tenant=ts, op="hedge")
-            self.obs.observe("serve_latency_ms", lat_ms, tenant=ts)
-            self.obs.observe("serve_stage_ms", wait_ms, stage="queue")
-            self.obs.observe("serve_stage_ms", lat_ms - wait_ms, stage="lane")
-            if tier_h or tier_m:
-                self.obs.inc("serve_tier_total", tier_h, tenant=ts,
-                             tier="vector", outcome="hit")
-                self.obs.inc("serve_tier_total", tier_m, tenant=ts,
-                             tier="vector", outcome="miss")
-            self._emit_trace("query", r.rid, r.tenant, r.arrival_s,
-                             r.admit_s, r.reserved_ru, out, plan, B, bucket,
-                             ru_q, lat_ms, pspans=pspans,
-                             extra_spans=rerank_spans,
-                             anomalies=() if complete
-                             else (ANOMALY_DEGRADED,),
-                             beam_width=beam_width)
+        tracing = self.tracer.enabled
+        with span("serve.respond", batch=B):
+            for i, r in enumerate(batch):
+                # start_s includes lane queue wait: under replica dispatch
+                # a batch that finds every lane busy pays that wait in its
+                # latency percentiles, exactly like a real executor pool
+                wait_ms = (out.start_s - r.arrival_s) * 1000.0
+                lat_ms = (out.end_s - r.arrival_s) * 1000.0
+                assert r.rid not in self.responses, (
+                    f"rid {r.rid} already answered: one admitted request "
+                    f"must produce exactly one response/latency sample "
+                    f"(hedge and retry duplicates are lane-plane internals)"
+                )
+                self.responses[r.rid] = ServeResponse(
+                    rid=r.rid, status=200, ids=ids[i], dists=dists[i],
+                    ru=ru_q, plan=plan, latency_ms=lat_ms, wait_ms=wait_ms,
+                    batch_size=B, complete=complete,
+                )
+                answered_s = now_s() if tracing else 0.0
+                self.metrics.queries_ok += 1
+                if not complete:
+                    self.metrics.queries_degraded += 1
+                    self.obs.inc("serve_degraded_total", tenant=str(r.tenant))
+                self.metrics.latency_ms.observe(lat_ms)
+                self.metrics.wait_ms.observe(wait_ms)
+                self._settle(r.tenant, ru_q, r.reserved_ru)
+                ts = str(r.tenant)
+                self.obs.inc("serve_requests_total", tenant=ts, kind="query",
+                             status="200")
+                self.obs.inc("serve_ru_total", work_q, tenant=ts, op="query")
+                if out.hedge_ru:
+                    self.obs.inc("serve_ru_total", hedge_q, tenant=ts,
+                                 op="hedge")
+                self.obs.observe("serve_latency_ms", lat_ms, tenant=ts)
+                self.obs.observe("serve_stage_ms", wait_ms, stage="queue")
+                self.obs.observe("serve_stage_ms", lat_ms - wait_ms,
+                                 stage="lane")
+                if tier_h or tier_m:
+                    self.obs.inc("serve_tier_total", tier_h, tenant=ts,
+                                 tier="vector", outcome="hit")
+                    self.obs.inc("serve_tier_total", tier_m, tenant=ts,
+                                 tier="vector", outcome="miss")
+                if tracing:
+                    self._emit_trace(
+                        "query", r.rid, r.tenant, r.submit_s, r.dequeued_s,
+                        answered_s, r.reserved_ru, out, plan, B, bucket,
+                        ru_q, batch_id=batch_id, failed=failed,
+                        anomalies=() if complete else (ANOMALY_DEGRADED,),
+                        beam_width=beam_width)
+            self.metrics.answers += B
+        return ru_total
 
     # ------------------------------------------------------------------
     # trace plane
     # ------------------------------------------------------------------
-    @staticmethod
-    def _partition_spans(info: dict) -> list:
-        """(latency_ms, attrs) per searched partition from a fan-out info
-        dict — the RU plus the hop/expansion/cmps counters the RU/latency
-        split is computed from (store.ru.counters_for_ru /
-        counters_for_latency)."""
-        pids = info.get("partition_ids", ())
-        stats = info.get("stats_per_partition") or [None] * len(pids)
-        out = []
-        for pid, ru_i, lat_i, st in zip(pids, info["ru_per_partition"],
-                                        info["server_latencies_ms"], stats):
-            attrs = dict(pid=int(pid), ru=float(ru_i))
-            if st is not None:
-                attrs.update(hops=float(st.hops),
-                             expansions=float(st.expansions),
-                             cmps=float(st.cmps), plan=st.plan,
-                             tier_hits=float(getattr(st, "tier_hits", 0.0)),
-                             tier_misses=float(
-                                 getattr(st, "tier_misses", 0.0)))
-            out.append((float(lat_i), attrs))
-        return out
-
-    def _tier_totals(self, pspans: Sequence) -> tuple[float, float]:
-        """Per-query paged-tier touches summed over the fan-out (partition
-        stats carry per-query means, so the sum IS the per-request
-        share)."""
-        h = sum(float(a.get("tier_hits", 0.0)) for _, a in pspans)
-        m = sum(float(a.get("tier_misses", 0.0)) for _, a in pspans)
-        return h, m
-
-    def _rerank_spans(self, pspans: Sequence) -> list:
-        """One rerank child span per partition that touched the paged
-        vector tier: duration = the modelled miss-fetch time, attrs carry
-        the hit/miss counts (the trace-plane face of ISSUE 10)."""
-        us_pp = 0.0
-        parts = self.collection.partitions
-        if parts:
-            us_pp = parts[0].providers.meter.cfg.us_per_vector_page
-        out = []
-        for _, a in pspans:
-            if "tier_hits" not in a:
-                continue
-            th, tm = a["tier_hits"], a["tier_misses"]
-            if th == 0.0 and tm == 0.0:
-                continue
-            out.append(dict(
-                name=f"rerank[p{a['pid']}]", stage="rerank",
-                dur_ms=tm * us_pp / 1000.0,
-                attrs=dict(pid=a["pid"], tier_hits=th, tier_misses=tm),
-            ))
-        return out
-
     def _note_throttle(self, kind: str, rid: int, tenant: Any,
-                       retry_after_s: float):
-        """Registry + trace bookkeeping for a 429 rejection."""
+                       retry_after_s: float, at_s: float):
+        """Registry + trace bookkeeping for a 429 rejection at host time
+        ``at_s``."""
         ts = str(tenant)
         self.obs.inc("serve_requests_total", tenant=ts, kind=kind,
                      status="429")
@@ -689,59 +674,55 @@ class VectorServeEngine:
         tr = self.tracer.begin(kind, tenant, rid)
         if tr is None:
             return
-        now = self.clock.now()
-        tr.span("admission", "admission", now, now, throttled=True,
+        tr.span("admission", "admission", at_s, at_s, throttled=True,
                 retry_after_s=retry_after_s)
         self.tracer.finish(tr, status=429, ru=0.0, latency_ms=0.0,
-                           t0_s=now, t1_s=now)
+                           t0_s=at_s, t1_s=at_s)
 
-    def _emit_trace(self, kind: str, rid: int, tenant: Any, arrival_s: float,
-                    admit_s: float, reserved_ru: float, out, plan: str,
-                    batch_size: int, bucket: int, ru: float, lat_ms: float,
-                    pspans: Sequence = (), extra_spans: Sequence = (),
-                    anomalies: tuple = (),
+    def _emit_trace(self, kind: str, rid: int, tenant: Any, submit_s: float,
+                    start_s: float, end_s: float, reserved_ru: float, out,
+                    plan: str, batch_size: int, bucket: int, ru: float,
+                    batch_id: int = -1, failed: Sequence = (),
+                    events: Sequence = (), anomalies: tuple = (),
                     beam_width: Optional[int] = None):
-        """Record one served request's lifecycle trace from its dispatch
-        outcome. The root spans — queue [arrival → lane start] and lane
-        [lane start → completion] — tile the request interval, so their
-        summed duration equals the recorded latency (the reconciliation
-        invariant ``trace.validate_trace_record`` enforces). admission and
-        batch_form are point events on the root; partition fan-out, the
-        hedge duplicate, fault retries and the merge hang off the lane
-        span as its parallel decomposition."""
+        """Record one served request on the host clock. The root spans —
+        admission (point, at submit), queue [submit → start], batch_form
+        (point, at start) and lane [start → answer stored at ``end_s``] —
+        tile the request's interval, so their durations sum to its
+        latency (the invariant ``trace.validate_trace_record`` checks).
+        Point events hang off the lane at its start: a retry per faulted
+        lane and the hedge duplicate (the lanes are simulated, so their
+        durations are not time), each partition a degraded fan-out lost,
+        and the host plan's ``events`` (dicts of name, stage, attrs)."""
         tr = self.tracer.begin(kind, tenant, rid)
         if tr is None:
             return
-        start, end = out.start_s, out.end_s
-        q1 = min(max(start, arrival_s), end)  # tiling-safe lane boundary
-        tr.span("admission", "admission", admit_s, admit_s,
+        tr.batch_id = batch_id
+        tr.span("admission", "admission", submit_s, submit_s,
                 reserved_ru=reserved_ru)
-        tr.span("queue", "queue", arrival_s, q1)
+        tr.span("queue", "queue", submit_s, start_s)
         attrs = dict(batch_size=batch_size, bucket=bucket, plan=plan)
         if beam_width is not None:  # the resolved chunk-plan W (policy-set)
             attrs["beam_width"] = beam_width
-        tr.span("batch_form", "batch_form", q1, q1, **attrs)
-        lane = tr.span("lane", "lane", q1, end, lane=out.lane,
+        tr.span("batch_form", "batch_form", start_s, start_s, **attrs)
+        lane = tr.span("lane", "lane", start_s, end_s, lane=out.lane,
                        hedged=out.hedged, straggled=out.straggled,
                        retried_lanes=list(out.retried_lanes), ru=ru)
-        for lat_i, attrs in pspans:
-            tr.span(f"partition[p{attrs['pid']}]", "partition",
-                    start, start + lat_i / 1000.0, parent=lane, **attrs)
-        for sp in extra_spans:
-            tr.span(sp["name"], sp["stage"], start,
-                    start + sp["dur_ms"] / 1000.0, parent=lane,
-                    **sp.get("attrs", {}))
         for lid in out.retried_lanes:
-            tr.span(f"retry[lane{lid}]", "retry", start, start, parent=lane,
-                    lane=lid)
+            tr.span(f"retry[lane{lid}]", "retry", start_s, start_s,
+                    parent=lane, lane=lid)
         if out.hedged:
-            tr.span("hedge", "hedge", out.hedge_start_s, out.hedge_end_s,
-                    parent=lane, lane=out.hedge_lane, won=out.hedge_won,
-                    ru=out.hedge_ru)
-        ov = min(self.cfg.dispatch_overhead_ms / 1000.0, end - start)
-        tr.span("merge", "merge", end - max(ov, 0.0), end, parent=lane)
-        self.tracer.finish(tr, status=200, ru=ru, latency_ms=lat_ms,
-                           t0_s=arrival_s, t1_s=end, anomalies=anomalies)
+            tr.span("hedge", "hedge", start_s, start_s, parent=lane,
+                    lane=out.hedge_lane, won=out.hedge_won, ru=out.hedge_ru)
+        for pid, err in failed:
+            tr.span(f"partition[p{pid}]", "partition", start_s, start_s,
+                    parent=lane, pid=int(pid), failed=True, error=str(err))
+        for ev in events:
+            tr.span(ev["name"], ev["stage"], start_s, start_s, parent=lane,
+                    **ev.get("attrs", {}))
+        self.tracer.finish(tr, status=200, ru=ru,
+                           latency_ms=(end_s - submit_s) * 1000.0,
+                           t0_s=submit_s, t1_s=end_s, anomalies=anomalies)
 
     def _spmd(self) -> SpmdFanout:
         if self._spmd_fanout is None:
@@ -768,84 +749,83 @@ class VectorServeEngine:
         failed: list = []  # (pid, error) per unreachable partition
         if not partitions:  # empty tenant collection: nothing to scan
             return (np.full((B, k), -1, np.int64), np.full((B, k), np.inf),
-                    0.0, 0.0, plan, [], failed)
+                    0.0, 0.0, plan, (0.0, 0.0), failed)
         padded = smod.pad_batch_np(
             queries, smod.next_bucket(B, self.cfg.batch_buckets)
         )
         ids_l, d_l, ru, service_ms = [], [], 0.0, 0.0
-        pspans: list = []  # (latency_ms, attrs) per scanned partition
+        tier_h = tier_m = 0.0  # paged-tier touches per query, summed
         answered = 0
         for p in partitions:
             if health is not None and not health(p):
                 failed.append((p.pid, "replica set down"))
                 continue
-            try:
-                pv = p.providers
-                scan_mask = pv.live
-                n_scan = p.num_docs
-                ru_p = 0.0
-                if predicate is not None:
-                    if p.num_docs == 0:
-                        answered += 1
-                        continue
-                    mask, _words, nreads = compile_partition_filter(p, predicate)
-                    # bill the compile's posting lookups even when the
-                    # partition is then skipped as a no-match
-                    ru_p += nreads * pv.meter.cfg.ru_per_prop_read
-                    if mask is None:
-                        ru += ru_p
-                        answered += 1
-                        continue
-                    scan_mask = mask & pv.live
-                    n_scan = int(scan_mask.sum())
-                ids, dists = fmod.brute_force(
-                    jnp.asarray(padded), jnp.asarray(pv.vectors),
-                    jnp.asarray(scan_mask), k=k, metric=p.index.cfg.metric,
-                )
-            except (CrashError, jax.errors.JaxRuntimeError):
-                # injected process kill, or a device fault (a compile
-                # failure, HBM exhausted): never degrade past it
-                raise
-            except Exception as e:  # noqa: BLE001 — degrade, don't fail
-                failed.append((p.pid, f"{type(e).__name__}: {e}"))
-                continue
-            answered += 1
-            ids_l.append(p.index._to_doc_ids(np.asarray(ids))[:B])
-            d_l.append(np.asarray(dists)[:B])
-            # every lane scans the (filtered) subset: full scan at
-            # quantized-ish cost, PER QUERY (RU must not deflate with
-            # batch size)
-            ru_p += 0.5 * n_scan * 0.0125 * B
-            # paged-tier touch (ISSUE 10): an exact scan streams every
-            # scanned vector through once, so non-resident pages bill one
-            # fetch for the whole batch (shared stream, NOT ×B) and the
-            # sequential sweep must not evict the working set (admit=False
-            # scan resistance)
-            th = tm = 0
-            pages = getattr(pv, "pages", None)
-            if pages is not None and n_scan:
-                th, tm, _ = pages.touch(np.nonzero(np.asarray(scan_mask))[0],
-                                        admit=False)
-                ru_p += tm * pv.meter.cfg.ru_per_vector_page
-            ru += ru_p
-            # partitions scan in parallel — client latency tracks the worst
-            # partition (§4.3), same model as the graph path
-            lat_p = pv.meter.latency_ms(OpCounters(quant_reads=n_scan,
-                                                   vector_page_misses=tm))
-            service_ms = max(service_ms, lat_p)
-            pspans.append((lat_p, dict(pid=int(p.pid), ru=ru_p,
-                                       n_scan=n_scan, plan=plan,
-                                       tier_hits=float(th) / max(B, 1),
-                                       tier_misses=float(tm) / max(B, 1))))
+            with span("search.partition", pid=int(p.pid)):
+                try:
+                    pv = p.providers
+                    scan_mask = pv.live
+                    n_scan = p.num_docs
+                    ru_p = 0.0
+                    if predicate is not None:
+                        if p.num_docs == 0:
+                            answered += 1
+                            continue
+                        mask, _words, nreads = compile_partition_filter(p, predicate)
+                        # bill the compile's posting lookups even when the
+                        # partition is then skipped as a no-match
+                        ru_p += nreads * pv.meter.cfg.ru_per_prop_read
+                        if mask is None:
+                            ru += ru_p
+                            answered += 1
+                            continue
+                        scan_mask = mask & pv.live
+                        n_scan = int(scan_mask.sum())
+                    ids, dists = fmod.brute_force(
+                        jnp.asarray(padded), jnp.asarray(pv.vectors),
+                        jnp.asarray(scan_mask), k=k, metric=p.index.cfg.metric,
+                    )
+                except (CrashError, jax.errors.JaxRuntimeError):
+                    # injected process kill, or a device fault (a compile
+                    # failure, HBM exhausted): never degrade past it
+                    raise
+                except Exception as e:  # noqa: BLE001 — degrade, don't fail
+                    failed.append((p.pid, f"{type(e).__name__}: {e}"))
+                    continue
+                answered += 1
+                ids_l.append(p.index._to_doc_ids(np.asarray(ids))[:B])
+                d_l.append(np.asarray(dists)[:B])
+                # every lane scans the (filtered) subset: full scan at
+                # quantized-ish cost, PER QUERY (RU must not deflate with
+                # batch size)
+                ru_p += 0.5 * n_scan * 0.0125 * B
+                # paged-tier touch: an exact scan streams every
+                # scanned vector through once, so non-resident pages bill one
+                # fetch for the whole batch (shared stream, NOT ×B) and the
+                # sequential sweep must not evict the working set (admit=False
+                # scan resistance)
+                th = tm = 0
+                pages = getattr(pv, "pages", None)
+                if pages is not None and n_scan:
+                    th, tm, _ = pages.touch(np.nonzero(np.asarray(scan_mask))[0],
+                                            admit=False)
+                    ru_p += tm * pv.meter.cfg.ru_per_vector_page
+                ru += ru_p
+                # partitions scan in parallel — client latency tracks the worst
+                # partition (§4.3), same model as the graph path
+                lat_p = pv.meter.latency_ms(OpCounters(quant_reads=n_scan,
+                                                       vector_page_misses=tm))
+                service_ms = max(service_ms, lat_p)
+                tier_h += float(th) / max(B, 1)
+                tier_m += float(tm) / max(B, 1)
         if failed and answered == 0:
             raise AllPartitionsFailed(
                 f"exact scan: all partitions failed: {failed}"
             )
         if not ids_l:  # predicate matched nothing anywhere
             return (np.full((B, k), -1, np.int64), np.full((B, k), np.inf),
-                    ru, service_ms, plan, pspans, failed)
+                    ru, service_ms, plan, (tier_h, tier_m), failed)
         ids, dists = merge_topk(ids_l, d_l, k)
-        return ids, dists, ru, service_ms, plan, pspans, failed
+        return ids, dists, ru, service_ms, plan, (tier_h, tier_m), failed
 
     # ------------------------------------------------------------------
     # host-path execution (filtered plans need the document store; the
@@ -861,22 +841,25 @@ class VectorServeEngine:
         service_ms) or (ids, dists, ru, service_ms, plan) — the 5-tuple
         form lets the body report the plan it actually executed (e.g. the
         per-partition aggregate of a filtered query). A 6th element may
-        carry trace child spans — dicts of (name, stage, dur_ms, attrs) —
-        which land under the request's lane span (e.g. a page's
-        per-partition fetch rounds from ``paged_fanout_search``)."""
+        carry trace point events — dicts of (name, stage, attrs) — which
+        land under the request's lane span (e.g. a page's per-partition
+        fetch rounds from ``paged_fanout_search``)."""
         kind = "page" if is_page else "query"
+        host_submit = now_s()
         rejected, reserved = self._admit(tenant)
         if rejected is not None:
-            self._note_throttle(kind, -1, tenant, rejected.retry_after_s)
+            self._note_throttle(kind, -1, tenant, rejected.retry_after_s,
+                                host_submit)
             raise Throttled(tenant, rejected.retry_after_s)
         submit_s = self.clock.now()
+        host_start = now_s()
 
         def run():
             out = fn()
             ids, dists, ru, service_ms = out[:4]
             body_plan = out[4] if len(out) > 4 else plan
-            extra_spans = out[5] if len(out) > 5 else ()
-            return ((ids, dists, body_plan, extra_spans),
+            events = out[5] if len(out) > 5 else ()
+            return ((ids, dists, body_plan, events),
                     service_ms + self.cfg.dispatch_overhead_ms, ru)
 
         # page bodies schedule their own multi-cursor refill rounds on the
@@ -887,7 +870,8 @@ class VectorServeEngine:
             # e.g. a user filter predicate raising: refund the reservation
             self.tenant_governor(tenant).refund(reserved)
             raise
-        ids, dists, plan_out, extra_spans = out.payload
+        host_end = now_s()
+        ids, dists, plan_out, events = out.payload
         ru_work = out.ru
         ru = out.ru + out.hedge_ru
         if out.hedged:
@@ -913,9 +897,9 @@ class VectorServeEngine:
         self.obs.observe("serve_latency_ms", lat_ms, tenant=ts)
         self.obs.observe("serve_stage_ms", wait_ms, stage="queue")
         self.obs.observe("serve_stage_ms", lat_ms - wait_ms, stage="lane")
-        self._emit_trace(kind, -1, tenant, submit_s, submit_s, reserved,
-                         out, plan_out, 1, 1, ru, lat_ms,
-                         extra_spans=extra_spans)
+        self._emit_trace(kind, -1, tenant, host_submit, host_start,
+                         host_end, reserved, out, plan_out, 1, 1, ru,
+                         events=events)
         return ServeResponse(rid=-1, status=200, ids=ids, dists=dists, ru=ru,
                              plan=plan_out, latency_ms=lat_ms, wait_ms=wait_ms,
                              batch_size=1)
@@ -1046,27 +1030,27 @@ class VectorServeEngine:
         self.obs.inc("serve_policy_total", knob="topology", action=dec.scale)
         tr = self.tracer.begin("policy", "engine", -1)
         if tr is not None:
-            tr.span(f"policy[{dec.scale}]", "policy", now, now,
+            at = tr.t0_s
+            tr.span(f"policy[{dec.scale}]", "policy", at, at,
                     action=dec.scale, detail=detail, reason=dec.reason,
                     queue_depth=sig.queue_depth, lanes=sig.lanes,
                     partitions=sig.partitions)
             self.tracer.finish(tr, status=200, ru=0.0, latency_ms=0.0,
-                               t0_s=now, t1_s=now)
+                               t0_s=at, t1_s=at)
 
-    def _interleave_ingest(self):
+    def _interleave_ingest(self) -> int:
         """Post-batch ingest drain. Static policy: exactly the configured
         interleave (the pre-policy behavior). Active policy: the current
         yield decision — 0 under latency pressure (the deferral is
         recorded as catch-up debt), ``catchup_chunks`` when the queue is
-        empty."""
+        empty. Returns the number of chunks applied."""
         if not self.policy.enabled:
-            self._drain_ingest(self.cfg.ingest_interleave)
-            return
+            return self._drain_ingest(self.cfg.ingest_interleave)
         n = self._decision.ingest_interleave
         if self._ingest_q and n < self.cfg.ingest_interleave:
             self.metrics.ingest_deferred_chunks += min(
                 self.cfg.ingest_interleave - n, len(self._ingest_q))
-        self._drain_ingest(n)
+        return self._drain_ingest(n)
 
     def _idle_ingest(self):
         """Idle-pump ingest drain. Static policy: the 1-chunk trickle.
@@ -1117,16 +1101,20 @@ class VectorServeEngine:
     # ------------------------------------------------------------------
     def _drain_ingest(self, n_chunks: int) -> int:
         """Apply up to ``n_chunks`` queued ingest mini-batches; returns
-        how many actually drained."""
+        how many actually drained. Each runs inside a ``serve.ingest``
+        span; with the tracer enabled its record (kind ``ingest``) holds
+        that span and the ``write.*`` and ``store.*`` spans under it."""
         drained = 0
         for _ in range(n_chunks):
             if not self._ingest_q:
                 return drained
             kind, apply_fn, n_ops, tenant = self._ingest_q.popleft()
             drained += 1
-            t0 = self.clock.now()
-            ru = float(apply_fn())
-            t1 = self.clock.advance(ru * self.cfg.ingest_ms_per_ru / 1000.0)
+            tr = self.tracer.begin("ingest", tenant, -1)
+            with (collect() if tr is not None else nullcontext()) as recs, \
+                    span("serve.ingest", kind=kind, docs=n_ops):
+                ru = float(apply_fn())
+            self.clock.advance(ru * self.cfg.ingest_ms_per_ru / 1000.0)
             self.metrics.ingest_ops += n_ops
             self.metrics.ingest_batches += 1
             self.metrics.ru_ingest_total += ru
@@ -1134,13 +1122,9 @@ class VectorServeEngine:
             self.obs.inc("serve_requests_total", tenant=ts, kind="ingest",
                          status="200")
             self.obs.inc("serve_ru_total", ru, tenant=ts, op="ingest")
-            tr = self.tracer.begin("ingest", tenant, -1)
             if tr is not None:
-                tr.span(f"ingest[{kind}]", "ingest", t0, t1, op=kind,
-                        n_ops=n_ops, ru=ru)
-                self.tracer.finish(tr, status=200, ru=ru,
-                                   latency_ms=(t1 - t0) * 1000.0,
-                                   t0_s=t0, t1_s=t1)
+                self.tracer.finish_measured(tr, recs, ru)
+                tr.spans[0].attrs["ru"] = ru
         return drained
 
     def flush_ingest(self):
@@ -1212,6 +1196,10 @@ class VectorServeEngine:
         snap["dispatch"] = self.executor.snapshot()
         snap["policy"] = self.policy_state()
         snap["memory"] = self.memory_snapshot()
+        # host-to-device copies of the partitions' arrays (store.upload)
+        pvs = [p.providers for p in self.collection.partitions]
+        snap["uploads"] = sum(pv.uploads for pv in pvs)
+        snap["upload_bytes"] = sum(pv.upload_bytes for pv in pvs)
         snap["tenants"] = {
             t: dict(available_ru=g.available, consumed_ru=g.consumed,
                     throttle_events=g.throttle_events,

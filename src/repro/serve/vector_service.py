@@ -400,18 +400,18 @@ class VectorCollectionService:
                 target, qv, pstate, page_size, beam_width=W,
                 slot_filters=slot_filters, executor=lane_exec,
             )
-            # per-fetch child spans for the trace plane: one span per
-            # partition page-fetch, labelled by refill round
-            fetch_spans = [
+            # per-fetch point events for the trace plane: one per
+            # partition page-fetch, labelled by refill round (the fetch
+            # times are modelled, so the events carry no duration)
+            fetches = [
                 dict(name=f"page.fetch[p{e['pid']}]", stage="partition",
-                     dur_ms=e["lat_ms"],
                      attrs=dict(pid=e["pid"], round=e["round"], ru=e["ru"]))
                 for e in info["fetch_log"]
             ]
             return (ids, dists, info["ru_total"] + compile_ru,
                     info["service_latency_ms"],
                     "paginated" if pred is None else "paginated-filtered",
-                    fetch_spans)
+                    fetches)
 
         resp = self.engine.execute_host(q.tenant, "paginated", body,
                                         is_page=True)

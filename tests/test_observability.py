@@ -1,12 +1,14 @@
-"""Observability plane (ISSUE 7): request-lifecycle traces, the labeled
+"""Observability plane: request records on the host clock, the labeled
 cost-attribution registry, the bounded streaming histogram, flight
 recorder retention, and the exporters.
 
 The invariants under test are *conservation laws*, not smoke checks:
 
-  * every served trace's root-span stage durations sum to its recorded
-    end-to-end latency (stages tile the request interval — a dashboard
-    built on them can never silently leak time), in every dispatch mode;
+  * every served record's root-span stage durations sum to its measured
+    host-clock latency (stages tile the request interval — a dashboard
+    built on them can never silently leak time), in every dispatch mode,
+    and its micro-batch's measured spans are in the ``batch`` record it
+    names;
   * exactly one latency sample per admitted request, even when the lane
     plane hedges a duplicate or retries across faulted lanes;
   * the labeled registry's RU totals reconcile with the engine-global
@@ -24,7 +26,6 @@ from repro.serve import (EngineConfig, ExactHistogram, Histogram,
                          VectorServeEngine, validate_trace_record)
 from repro.serve.trace import (ANOMALY_HEDGE, ANOMALY_THROTTLE,
                                FlightRecorder, Trace, Tracer)
-from repro.serve.metrics import SimClock
 
 from conftest import clustered_data
 
@@ -110,10 +111,12 @@ def test_streaming_histogram_bounded_and_monotone():
 
 @pytest.mark.parametrize("mode", ["serial", "replica", "spmd"])
 def test_stage_sums_reconcile_with_latency(mode):
-    """Every admitted query produces a schema-valid trace whose root-span
-    stage times sum (within clock resolution) to its recorded latency —
-    across all three dispatch planes. The replica plane runs with forced
-    stragglers + hedging so the anomalous path reconciles too."""
+    """Every admitted query produces a schema-valid record whose root-span
+    stage times sum (within clock resolution) to its host-clock latency —
+    across all three dispatch planes — and names the ``batch`` record
+    that holds its micro-batch's measured spans. The replica plane runs
+    with forced stragglers + hedging so the anomalous path reconciles
+    too."""
     svc, data, rng = make_multipart_service()
     hedged = mode == "replica"
     eng = VectorServeEngine(
@@ -129,17 +132,31 @@ def test_stage_sums_reconcile_with_latency(mode):
     eng.drain()
     assert all(eng.responses[r].status == 200 for r in rids)
 
-    recs = [r for r in eng.tracer.recorder.records() if r["kind"] == "query"]
+    all_recs = eng.tracer.recorder.records()
+    batches = {r["trace_id"]: r for r in all_recs if r["kind"] == "batch"}
+    recs = [r for r in all_recs if r["kind"] == "query"]
     assert len(recs) == len(rids) == eng.metrics.queries_ok
+    assert len(batches) == eng.metrics.batches
     for rec in recs:
         validate_trace_record(rec)  # raises on any stage-time leak
         stages = {s["stage"] for s in rec["spans"]}
-        assert {"admission", "queue", "batch_form", "lane",
-                "merge"} <= stages
-        # fan-out decomposition: one child span per searched partition
-        pids = {s["attrs"]["pid"] for s in rec["spans"]
-                if s["stage"] == "partition"}
-        assert len(pids) == len(svc.collection.partitions)
+        assert {"admission", "queue", "batch_form", "lane"} <= stages
+        b = batches[rec["batch_id"]]
+        # the query's answer was stored inside its micro-batch's dispatch
+        assert b["t0_s"] <= rec["t1_s"] <= b["t1_s"]
+    for b in batches.values():
+        validate_trace_record(b)
+        names = [s["name"] for s in b["spans"]]
+        assert names[0] == "serve.dispatch" and b["spans"][0]["parent"] == -1
+        assert {"serve.respond", "search.merge"} <= set(names)
+        if mode != "spmd":  # spmd searches every partition in one program
+            # fan-out decomposition: one measured span per partition,
+            # each holding the index's search steps
+            pids = {s["attrs"]["pid"] for s in b["spans"]
+                    if s["name"] == "search.partition"}
+            assert len(pids) == len(svc.collection.partitions)
+            assert {"search.prepare", "search.graph", "search.rerank",
+                    "search.finish"} <= set(names)
     if hedged:
         hedge_recs = [r for r in recs if ANOMALY_HEDGE in r["anomalies"]]
         assert eng.metrics.hedges > 0 and hedge_recs, \
@@ -333,7 +350,9 @@ def test_disabled_tracer_is_inert_and_result_identical():
     assert eng_off.tracer.begin("query", "t", 0) is None
     s = eng_off.tracer.stats()
     assert (s["started"], s["recorded"], s["retained"]) == (0, 0, 0)
-    assert eng_on.tracer.stats()["recorded"] == len(queries)
+    # one record per query plus one per micro-batch
+    assert eng_on.tracer.stats()["recorded"] == \
+        len(queries) + eng_on.metrics.batches
 
 
 # ---------------------------------------------------------------------------
@@ -341,10 +360,11 @@ def test_disabled_tracer_is_inert_and_result_identical():
 # ---------------------------------------------------------------------------
 
 def test_page_and_ingest_traces(tmp_path):
-    """Paged queries trace their per-partition fetch rounds under the lane
-    span; ingest mini-batches get single-root-span traces that reconcile
-    trivially. The JSONL exporter round-trips the schema and the
-    Prometheus exposition carries every family."""
+    """Paged queries record their per-partition fetch rounds as point
+    events under the lane span; each ingest mini-batch gets a record
+    rooted at its measured ``serve.ingest`` span with the write path's
+    spans under it. The JSONL exporter round-trips the schema and the
+    Prometheus exposition carries every family, span totals included."""
     svc, data, rng = make_multipart_service(seed=31)
     eng = svc.engine
     token, pages = None, 0
@@ -376,8 +396,11 @@ def test_page_and_ingest_traces(tmp_path):
                for s in all_fetches)
     for rec in ingest_recs:
         validate_trace_record(rec)
-        assert rec["spans"][0]["stage"] == "ingest"
-        assert rec["spans"][0]["attrs"]["ru"] == pytest.approx(rec["ru"])
+        root = rec["spans"][0]
+        assert (root["name"], root["stage"]) == ("serve.ingest", "serve")
+        assert root["attrs"]["kind"] == "upsert"
+        assert root["attrs"]["ru"] == pytest.approx(rec["ru"])
+        assert "write.insert" in {s["name"] for s in rec["spans"]}
 
     out = tmp_path / "traces.jsonl"
     n = eng.tracer.dump_jsonl(out)
@@ -386,11 +409,14 @@ def test_page_and_ingest_traces(tmp_path):
     for line in lines:
         validate_trace_record(json.loads(line))
 
-    prom = eng.obs.to_prometheus_text()
+    prom = eng.obs.to_prometheus_text(spans=eng.tracer.span_totals())
     for family in ("serve_requests_total", "serve_ru_total",
-                   "serve_latency_ms_sum", "serve_stage_ms"):
+                   "serve_latency_ms_sum", "serve_stage_ms",
+                   "serve_span_seconds_total", "serve_span_count",
+                   "serve_span_max_seconds"):
         assert family in prom
     assert 'op="ingest"' in prom and 'quantile="0.95"' in prom
+    assert 'serve_span_count{span="serve.ingest"}' in prom
 
 
 def test_registry_locks_label_names_and_kinds():
@@ -409,10 +435,10 @@ def test_registry_locks_label_names_and_kinds():
 
 
 def test_tracer_slo_tagging_on_simclock():
-    """SLO-violating traces are tagged from latency on the shared
-    SimClock — the always-capture rule for slow requests."""
-    clk = SimClock()
-    tr_fast = Tracer(clk, slo_ms=10.0)
+    """SLO-violating records are tagged from their latency — now the
+    host-clock latency, no longer SimClock's — the always-capture rule
+    for slow requests."""
+    tr_fast = Tracer(slo_ms=10.0)
     t = tr_fast.begin("query", "t", 1)
     t.span("queue", "queue", 0.0, 0.0)
     t.span("lane", "lane", 0.0, 0.02)
