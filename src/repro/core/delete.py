@@ -22,6 +22,7 @@ import jax
 import jax.numpy as jnp
 
 from . import prune as prmod
+from . import search as smod
 
 INF = jnp.float32(jnp.inf)
 
@@ -43,10 +44,15 @@ def inplace_delete(
     c_replace: int = 3,
     metric: str = "l2",
 ) -> jax.Array:
-    """Rewire the graph around deleted node p. Returns new neighbors."""
+    """Rewire the graph around deleted node p. Returns new neighbors.
+
+    Row b of the result depends only on row b, N_out(p), ``live`` and
+    ``vectors``, so each loop of Alg 6 runs as one batched pass.
+    """
     nout_p = neighbors[p]  # (R_slack,)
     safe_out = jnp.maximum(nout_p, 0)
     valid_out = (nout_p >= 0) & live[safe_out]
+    out_vecs = vectors[safe_out]
 
     # --- two-hop out-neighborhood ---------------------------------------
     twohop = neighbors[safe_out].reshape(-1)  # (R_slack^2,)
@@ -54,34 +60,33 @@ def inplace_delete(
     hood = jnp.concatenate([nout_p, twohop])  # candidate in-neighbors
     hood = jnp.where(hood == p, -1, hood)
 
-    # --- loop over the hood: b with p ∈ N_out(b) get rewired -------------
-    def fix_b(nb, b):
-        row = nb[jnp.maximum(b, 0)]
-        has_p = jnp.any(row == p) & (b >= 0) & live[jnp.maximum(b, 0)]
+    def closest_out(b, c):
+        """The c closest live members of N_out(p) to b, b excluded."""
+        b_vec = vectors[jnp.maximum(b, 0)]
+        if metric == "l2":
+            dd = jnp.sum((out_vecs - b_vec[None, :]) ** 2, -1)
+        else:
+            dd = -out_vecs @ b_vec
+        dd = jnp.where(valid_out & (nout_p != b), dd, INF)
+        take = jnp.argsort(dd)[:c]
+        return jnp.where(jnp.isfinite(dd[take]), nout_p[take], -1)
 
+    def scatter_rows(nb, ids, rows, write):
+        """nb with rows[i] at ids[i] where write[i]; the rest dropped."""
+        return nb.at[jnp.where(write, ids, nb.shape[0])].set(rows, mode="drop")
+
+    def first_live(ids):  # padding, dead nodes and repeats write nothing
+        return (ids >= 0) & live[jnp.maximum(ids, 0)] & ~smod.mask_duplicates(ids)
+
+    # --- first loop: b with p ∈ N_out(b) drop p and take its neighbors ----
+    def repair(b, row):
         # remove p, compact left
         no_p = jnp.where(row == p, -1, row)
         order = jnp.argsort(jnp.where(no_p >= 0, 0, 1), stable=True)
-        no_p = no_p[order]
-
-        # c closest live members of N_out(p) to b, excluding b itself
-        b_vec = vectors[jnp.maximum(b, 0)]
-        cand_vecs = vectors[safe_out]
-        if metric == "l2":
-            dd = jnp.sum((cand_vecs - b_vec[None, :]) ** 2, -1)
-        else:
-            dd = -cand_vecs @ b_vec
-        dd = jnp.where(valid_out & (nout_p != b), dd, INF)
-        closest = jnp.where(
-            jnp.isfinite(jnp.sort(dd)[:c_replace]),
-            nout_p[jnp.argsort(dd)[:c_replace]],
-            -1,
-        )
-
-        merged = jnp.concatenate([no_p, closest])  # (R_slack + c,)
+        merged = jnp.concatenate([no_p[order], closest_out(b, c_replace)])
         # dedup + prune to R if above bound, else compact to R_slack
         pruned = prmod.prune_with_vectors(
-            b_vec,
+            vectors[jnp.maximum(b, 0)],
             merged,
             vectors[jnp.maximum(merged, 0)],
             alpha=alpha,
@@ -96,40 +101,25 @@ def inplace_delete(
         )
         use_prune = deg_merged > R_slack
         # non-prune path: first R_slack unique entries of merged
-        eq = (merged[:, None] == merged[None, :]) & (merged[None, :] >= 0)
-        dup = jnp.any(eq & jnp.tril(jnp.ones_like(eq), k=-1).astype(bool), axis=1)
-        uniq = jnp.where(dup, -1, merged)
+        uniq = jnp.where(smod.mask_duplicates(merged), -1, merged)
         order2 = jnp.argsort(jnp.where(uniq >= 0, 0, 1), stable=True)
         compacted = uniq[order2][:R_slack]
         padded_prune = jnp.concatenate([pruned, jnp.full((R_slack - R,), -1, jnp.int32)])
-        new_row = jnp.where(use_prune, padded_prune, compacted)
+        return jnp.where(use_prune, padded_prune, compacted)
 
-        out = jnp.where(has_p, new_row, row)
-        return nb.at[jnp.maximum(b, 0)].set(out), None
-
-    neighbors, _ = jax.lax.scan(fix_b, neighbors, hood)
+    rows = neighbors[jnp.maximum(hood, 0)]  # (R_slack + R_slack^2, R_slack)
+    has_p = jnp.any(rows == p, axis=1) & first_live(hood)
+    neighbors = scatter_rows(neighbors, hood, jax.vmap(repair)(hood, rows), has_p)
 
     # --- second loop of Alg 6: stitch N_out(p) among themselves ----------
-    def stitch(nb, b):
-        ok = (b >= 0) & live[jnp.maximum(b, 0)]
-        b_vec = vectors[jnp.maximum(b, 0)]
-        cand_vecs = vectors[safe_out]
-        if metric == "l2":
-            dd = jnp.sum((cand_vecs - b_vec[None, :]) ** 2, -1)
-        else:
-            dd = -cand_vecs @ b_vec
-        dd = jnp.where(valid_out & (nout_p != b), dd, INF)
-        closest = jnp.argsort(dd)[:1]  # c=1 sibling link keeps degree churn low
-        sib = jnp.where(jnp.isfinite(dd[closest]), nout_p[closest], -1)[0]
-
-        row = nb[jnp.maximum(b, 0)]
-        deg = (row >= 0).sum()
-        already = jnp.any(row == sib) | (sib < 0)
-        appended = jnp.where(jnp.arange(row.shape[0]) == deg, sib, row)
-        can = ok & ~already & (deg < row.shape[0])
-        return nb.at[jnp.maximum(b, 0)].set(jnp.where(can, appended, row)), None
-
-    neighbors, _ = jax.lax.scan(stitch, neighbors, nout_p)
+    # c=1 sibling link keeps degree churn low
+    sib = jax.vmap(lambda b: closest_out(b, 1)[0])(nout_p)
+    rows = neighbors[safe_out]
+    deg = (rows >= 0).sum(axis=1)
+    already = jnp.any(rows == sib[:, None], axis=1) | (sib < 0)
+    appended = jnp.where(jnp.arange(R_slack)[None, :] == deg[:, None], sib[:, None], rows)
+    can = first_live(nout_p) & ~already & (deg < R_slack)
+    neighbors = scatter_rows(neighbors, nout_p, appended, can)
 
     # clear p's own list
     neighbors = neighbors.at[p].set(jnp.full((R_slack,), -1, jnp.int32))

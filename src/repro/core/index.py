@@ -87,6 +87,7 @@ class DiskANNIndex:
         self._pending: list[int] = []  # slots awaiting first graph build
         self._requant_cursor = 0  # background re-encode progress
         self._consolidate_cursor = 0
+        self.repair_rows = 0  # graph rows the in-place deletes rewrote
         # tier touches of the most recent next_page() call (pagination has
         # no QueryStats of its own; the partition layer folds these into
         # the page_stats delta)
@@ -433,7 +434,7 @@ class DiskANNIndex:
                     c_replace=cfg.c_replace, metric=cfg.metric,
                 ))
             with span("write.diff"):
-                self._write_neighbor_diff(old_nb, new_nb)
+                self.repair_rows += self._write_neighbor_diff(old_nb, new_nb)
         if slot == self.medoid and self.num_live:
             self.medoid = int(
                 g.compute_medoid(
@@ -459,8 +460,8 @@ class DiskANNIndex:
         self._write_neighbor_diff(old_nb, np.asarray(new_nb))
         self._consolidate_cursor = (self._consolidate_cursor + chunk) % max(self.count, 1)
 
-    def _write_neighbor_diff(self, old_nb: np.ndarray, new_nb: np.ndarray):
-        """Write only the rows a graph repair changed, through the provider.
+    def _write_neighbor_diff(self, old_nb: np.ndarray, new_nb: np.ndarray) -> int:
+        """Write only the rows a graph repair changed, through the provider; return how many.
 
         Durable providers log `set_neighbors` to their WAL; a direct
         whole-array store would leave the repair invisible to replay, so
@@ -472,6 +473,7 @@ class DiskANNIndex:
         # the repair kernels donate the provider's cached device buffer, so
         # the materialize cache is stale even when no row changed
         self.pv._dirty()
+        return int(changed.size)
 
     # ------------------------------------------------------------------
     # queries (§3.5)

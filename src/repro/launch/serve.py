@@ -111,7 +111,8 @@ def main(argv=None):
     print(f"host path: {snap['answers']} answers held "
           f"{snap['answer_hold_ns'] * 1e-6:.3f} ms in all behind "
           f"interleaved writes; {snap['uploads']} array uploads, "
-          f"{snap['upload_bytes'] / 2**20:.1f} MiB")
+          f"{snap['upload_bytes'] / 2**20:.1f} MiB; {snap['repair_rows']} "
+          f"graph rows rewritten by deletes")
     if args.trace_out:
         n = svc.engine.tracer.dump_jsonl(args.trace_out)
         print(f"wrote {n} trace records to {args.trace_out}")

@@ -1200,6 +1200,8 @@ class VectorServeEngine:
         pvs = [p.providers for p in self.collection.partitions]
         snap["uploads"] = sum(pv.uploads for pv in pvs)
         snap["upload_bytes"] = sum(pv.upload_bytes for pv in pvs)
+        snap["repair_rows"] = sum(
+            p.index.repair_rows for p in self.collection.partitions)
         snap["tenants"] = {
             t: dict(available_ru=g.available, consumed_ru=g.consumed,
                     throttle_events=g.throttle_events,

@@ -20,6 +20,7 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro.configs import cosmosann
+from repro.core import delete as dmod
 from repro.core import flat as fmod
 from repro.core import insert as imod
 from repro.core import search as smod
@@ -129,6 +130,17 @@ def test_service_insert_path_compiles_for_v5e(shape, program):
              shape((P, cap), i32))),
     }[program]
     _compile(fn, *args)
+
+
+def test_inplace_delete_compiles_for_v5e(shape):
+    """The batched repair over the whole two-hop hood (R_slack + R_slack²
+    rows, each gathering its merged candidates' 768-D coordinates) fits
+    the chip."""
+    nb, _, _, live = _graph(shape)
+    dmod.inplace_delete.lower(
+        nb, live, shape((N, CFG.dim), jnp.float32), shape((), jnp.int32),
+        R=CFG.R, R_slack=CFG.R_slack, alpha=1.2, c_replace=3, metric="l2",
+    ).compile()
 
 
 def test_rerank_compiles_for_v5e(shape):
