@@ -228,14 +228,32 @@ def positions(corpus: dat.Corpus, ids: np.ndarray) -> np.ndarray:
 class RunView:
     """What a per-layer metric reader may look at: the whole window's
     results, and the traced span's device trace and counts
-    (``traced.batches``, ``traced.answered``, ``traced.write_ops``, the
-    search-round counters ``traced.hops_weighted``/``hops_lanes``, and the
-    queries handed over in it, ``window.*[slice(*traced.sent)]``)."""
+    (``traced.batches``, ``traced.answered``, and the queries handed over
+    in it, ``window.*[slice(*traced.sent)]``).
+
+    The program's own counters and spans, by name, over the window
+    (``window.counters``, ``window.spans``) and over the traced span
+    (``traced.counters``, ``traced.spans``): counter name -> increase,
+    span name -> (count, total ns), from ``window.program_state``; and
+    its spans on the device trace's clock, ``trace.spans(name)`` and
+    ``trace.span_s(prefixes)``. A metric that reads any of them is a
+    file in ``metrics/`` and nothing else."""
     cell: Cell
     window: WindowResult
     trace: Optional[object]  # trace.TraceSummary of the traced span
     traced: Optional[Profile]
     peaks: dict
+
+
+def layer_metrics(cell: Cell, view: RunView) -> dict:
+    """The cell's per-layer metrics, each from its reader; a reader that
+    finds nothing to read leaves its metric out."""
+    out = {}
+    for m in cell.per_layer:
+        v = metric_reader(m.name)(view)
+        if v is not None:
+            out[m.name] = {"value": v, "unit": m.unit}
+    return out
 
 
 def end_to_end(cell: Cell, w: WindowResult, check: chk.Check,
@@ -318,7 +336,8 @@ def execute(cell: Cell, seed: int, seconds: float, trace: bool,
         log(f"trace: {profile.seconds:.1f}s of the window from "
             f"{profile.offset:.1f}s, {len(events)} events read and reduced "
             f"in {time.perf_counter() - t_read:.3f}s; {profile.batches} "
-            f"micro-batches, {profile.write_ops} write ops in it")
+            f"micro-batches, {profile.counters.get('ingest_ops', 0)} docs "
+            "written in it")
     log(f"set-up: {setup_s:.3f}s (load {setup.load_s:.3f}s)")
     log(f"window: {len(offsets)} queries due in {seconds}s, "
         f"{w.batches} micro-batches, {w.write_ops} write ops applied")
@@ -336,6 +355,15 @@ def execute(cell: Cell, seed: int, seconds: float, trace: bool,
     log(f"full collections in window: {len(pauses.pauses)}, longest "
         f"{max(pauses.pauses, default=0.0) * 1e3:.3f} ms "
         f"({gc.get_freeze_count()} objects frozen after set-up)")
+    log(f"program state: {len(w.state_s)} snapshots, longest "
+        f"{w.state_s.max(initial=0) * 1e6:.1f} us; over the window "
+        f"{w.counters.get('answers', 0)} answers, "
+        f"{w.counters.get('ingest_ops', 0)} docs written, "
+        f"{w.counters.get('upload_bytes', 0)} bytes uploaded, "
+        f"{w.counters.get('repair_rows', 0)} rows repaired")
+    top = sorted(w.spans.items(), key=lambda kv: -kv[1][1])[:8]
+    log("program spans over the window (count, total ms): " + ", ".join(
+        f"{n} {c} {t * 1e-6:.1f}" for n, (c, t) in top))
     log(f"peak_bytes_in_use: {peak}")
     readback = read_back(cell, setup, seed)
     if after is not None:
@@ -369,12 +397,8 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool,
                 counter or CompileCounter())
     check = compare(o)
     if trace:
-        view = RunView(cell, o.window, o.trace, o.profile, peaks)
-        metrics = {}
-        for m in cell.per_layer:
-            v = metric_reader(m.name)(view)
-            if v is not None:
-                metrics[m.name] = {"value": v, "unit": m.unit}
+        metrics = layer_metrics(cell, RunView(cell, o.window, o.trace,
+                                              o.profile, peaks))
     else:
         metrics = end_to_end(cell, o.window, check, o.setup_s)
     device = {"platform": devices[0].platform, "kind": devices[0].device_kind,

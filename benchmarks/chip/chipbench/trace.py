@@ -5,22 +5,28 @@ The trace is read with ``jax.profiler.ProfileData`` into plain events
 (plane, line, name, start, duration in ns). Device planes are those named
 ``/device:<accelerator>:<n>``; on them the ``XLA Ops`` line holds the
 operations that ran and the ``XLA Modules`` line the compiled programs
-(``jit_<function name>``). Host spans are the harness's
-``TraceAnnotation``s; ``bench.traced`` marks the traced span of the
-measured window.
+(``jit_<function name>``). Host spans are ``TraceAnnotation``s named in
+the form ``<layer>.<step>``, lowercase words joined by dots: the
+harness's own (``engine.pump``, ``harness.wait``; ``bench.traced`` marks
+the traced span of the measured window) and the program's
+(``repro.core.spans``: ``serve.dispatch``, ``search.prepare``,
+``write.repair``, ``store.upload``, ...), with their metadata as stats.
+Any span of that form is kept, so a new family of the program's spans
+needs no change here.
 """
 from __future__ import annotations
 
+import bisect
 import dataclasses
 import gzip
-import itertools
+import heapq
 import json
 import re
 from pathlib import Path
 from typing import Iterable, Optional
 
 WINDOW = "bench.traced"
-HOST_PREFIXES = ("bench.", "engine.", "service.", "harness.")
+SPAN_RE = re.compile(r"^[a-z][a-z0-9_]*(\.[a-z][a-z0-9_]*)+$")
 OPS_LINE = "XLA Ops"
 MODULES_LINE = "XLA Modules"
 
@@ -32,6 +38,7 @@ class Event:
     name: str
     start_ns: float
     dur_ns: float
+    stats: Optional[dict] = None  # a host span's metadata, such as docs
 
     @property
     def end_ns(self) -> float:
@@ -44,7 +51,7 @@ def is_device_plane(name: str) -> bool:
 
 def read_xplane(path: Path) -> list[Event]:
     """The events of one ``.xplane.pb`` that this reduction uses: device
-    ops and modules, and the harness's host spans."""
+    ops and modules, and the host spans (``SPAN_RE``) with their stats."""
     from jax.profiler import ProfileData
 
     pd = ProfileData.from_file(str(path))
@@ -55,10 +62,15 @@ def read_xplane(path: Path) -> list[Event]:
             if dev and line.name not in (OPS_LINE, MODULES_LINE):
                 continue
             for e in line.events:
-                if not dev and not e.name.startswith(HOST_PREFIXES):
+                if dev:
+                    stats = None
+                elif SPAN_RE.match(e.name):
+                    stats = dict(e.stats)
+                else:
                     continue
                 out.append(Event(plane.name, line.name, e.name,
-                                 float(e.start_ns), float(e.duration_ns)))
+                                 float(e.start_ns), float(e.duration_ns),
+                                 stats))
     return out
 
 
@@ -123,12 +135,23 @@ class TraceSummary:
             return 0.0
         return sum(self.busy_ns.values()) / len(self.busy_ns) * 1e-9
 
-    def program_s(self, functions: Iterable[str]) -> Optional[float]:
+    def spans(self, name: str) -> list[Event]:
+        """The host spans called ``name`` that lie wholly in the traced
+        span, in the order they started."""
+        lo, hi = self.window_ns
+        return sorted((e for e in self.host if e.name == name
+                       and lo <= e.start_ns and e.end_ns <= hi),
+                      key=lambda e: e.start_ns)
+
+    def program_s(self, functions: Iterable[str],
+                  within: Optional[list[Event]] = None) -> Optional[float]:
         """Device seconds of the programs compiled from ``functions`` (jit
-        function names) that started in the span; None when none did."""
+        function names) that started in the span, or in one of the
+        ``within`` host spans; None when none did."""
         fns = tuple(functions)
         lo, hi = self.window_ns
         hits = [e.dur_ns for e in self.modules if lo <= e.start_ns < hi
+                and (within is None or _inside(e.start_ns, within))
                 and any(_matches(e.name, f) for f in fns)]
         if not hits:
             return None
@@ -145,12 +168,9 @@ class TraceSummary:
         return [[k, v] for k, v in
                 sorted(agg.items(), key=lambda kv: -kv[1])[:top]]
 
-    def idle_gaps(self, top: int = 10) -> list[list]:
-        """Device idle time in the span, by cause, largest first: [[name,
-        seconds], ...]. Idle time inside a running program (between its
-        operations) is ``device.in_program``; idle time outside any
-        program goes to the harness span open on the host then
-        (``harness.loop`` where none was)."""
+    def _idle(self):
+        """(idle ns inside running programs, idle intervals outside any
+        program), both inside the span."""
         lo, hi = self.window_ns
         ops = _merge(_clip([(e.start_ns, e.end_ns)
                             for e in self.ops or self.modules], lo, hi))
@@ -158,28 +178,89 @@ class TraceSummary:
                              lo, hi))
         gaps = _complement(ops, lo, hi)
         outside = _subtract(gaps, progs)
-        idle = sum(e - s for s, e in gaps)
-        agg = {"device.in_program": idle - sum(e - s for s, e in outside)}
-        # the harness's spans follow one another without nesting, so one
-        # pointer walks both sorted lists
-        spans = sorted((e.start_ns, e.end_ns, e.name) for e in self.host
-                       if e.name != WINDOW)
-        j = 0
-        for gs, ge in outside:
-            while j < len(spans) and spans[j][1] <= gs:
-                j += 1
-            covered = 0.0
-            for s, e, name in itertools.islice(spans, j, None):
-                if s >= ge:
-                    break
-                ov = min(e, ge) - max(s, gs)
-                if ov > 0:
-                    agg[name] = agg.get(name, 0.0) + ov
-                    covered += ov
-            agg["harness.loop"] = agg.get("harness.loop", 0.0) + (
-                ge - gs - covered)
+        in_program = (sum(e - s for s, e in gaps)
+                      - sum(e - s for s, e in outside))
+        return in_program, outside
+
+    def _by_span(self, intervals) -> dict:
+        """``intervals`` split by the innermost host span open over each
+        part: {span name: ns}, with the parts under no span at None."""
+        spans = [e for e in self.host if e.name != WINDOW]
+        return _innermost([(e.start_ns, e.end_ns, e.name) for e in spans],
+                          intervals)
+
+    def idle_gaps(self, top: int = 10) -> list[list]:
+        """Device idle time in the span, by cause, largest first: [[name,
+        seconds], ...]. Idle time inside a running program (between its
+        operations) is ``device.in_program``; idle time outside any
+        program goes to the innermost host span open then, the program's
+        (``search.prepare``) inside the harness's (``engine.pump``), and to
+        ``harness.loop`` where none was. The causes sum to the idle
+        time."""
+        in_program, outside = self._idle()
+        agg = self._by_span(outside)
+        loop = agg.pop(None, 0.0)
+        agg["harness.loop"] = agg.get("harness.loop", 0.0) + loop
+        agg["device.in_program"] = in_program
         return [[k, v * 1e-9] for k, v in
                 sorted(agg.items(), key=lambda kv: -kv[1])[:top] if v > 0]
+
+    def span_s(self, prefixes: Iterable[str],
+               within: Optional[list[Event]] = None) -> float:
+        """Device-idle seconds outside any program whose innermost open host
+        span has a name starting with one of ``prefixes`` (``"search."``),
+        in the span, or only inside the ``within`` host spans."""
+        pre = tuple(prefixes)
+        _, outside = self._idle()
+        if within is not None:
+            outside = _intersect(outside, _merge(
+                [(e.start_ns, e.end_ns) for e in within]))
+        return sum(v for k, v in self._by_span(outside).items()
+                   if k is not None and k.startswith(pre)) * 1e-9
+
+
+def _inside(t: float, spans: list[Event]) -> bool:
+    return any(e.start_ns <= t < e.end_ns for e in spans)
+
+
+def _innermost(spans, intervals) -> dict:
+    """Split sorted, disjoint ``intervals`` at every span boundary; each
+    part goes to the innermost span open over all of it, the one that
+    opened last (of two that opened together, the one that closes first),
+    or to None. ``spans`` are (start, end, name)."""
+    spans = sorted(spans)
+    points = sorted({p for s, e, _ in spans for p in (s, e)})
+    out: dict = {}
+    heap: list = []  # (-start, end, name) of the spans opened so far
+    j = 0
+    for gs, ge in intervals:
+        lo = bisect.bisect_right(points, gs)
+        hi = bisect.bisect_left(points, ge)
+        cuts = [gs, *points[lo:hi], ge]
+        for a, b in zip(cuts, cuts[1:]):
+            while j < len(spans) and spans[j][0] <= a:
+                heapq.heappush(heap, (-spans[j][0], spans[j][1],
+                                      spans[j][2]))
+                j += 1
+            while heap and heap[0][1] <= a:
+                heapq.heappop(heap)
+            name = heap[0][2] if heap else None
+            out[name] = out.get(name, 0.0) + (b - a)
+    return out
+
+
+def _intersect(a, b):
+    """The parts common to two sorted lists of disjoint intervals."""
+    out, i, j = [], 0, 0
+    while i < len(a) and j < len(b):
+        s, e = max(a[i][0], b[j][0]), min(a[i][1], b[j][1])
+        if s < e:
+            out.append((s, e))
+        if a[i][1] < b[j][1]:
+            i += 1
+        else:
+            j += 1
+    return out
 
 
 def _complement(merged, lo, hi):

@@ -15,6 +15,11 @@ timer runs on its simulated clock and is never consulted):
   one write request (the engine's idle ingest);
 * otherwise it sleeps until the next due time.
 
+At the window's two ends, and at the traced span's, the loop also takes
+the program's counters and span totals (``program_state``); their
+differences are what per-layer metrics read as ``counters`` and
+``spans`` of the window and of the traced span.
+
 A query's latency runs from its due time to the moment its answer, ids on
 the host, has been popped: time it spent due but not yet handed over (the
 engine was busy) counts. The loop records, for each query, which corpus
@@ -32,6 +37,37 @@ import numpy as np
 from .deploy import WriteStream
 
 BACKLOG_OPS = 1  # the write stream keeps this many ops queued
+
+
+def program_state(eng) -> tuple[dict, dict]:
+    """The program's counters and span totals now: (counters, spans).
+
+    ``counters`` are the engine's numeric counters (``EngineMetrics``:
+    ``answers``, ``answer_hold_ns``, ``ingest_ops``, ``hops_*``, ...) and
+    the partitions' ``uploads``, ``upload_bytes`` and ``repair_rows``,
+    summed as ``engine.snapshot()`` sums them (that call also builds
+    read-outs the window has no use for); ``spans`` maps each span name of
+    ``repro.core.spans.totals()`` to (count, total ns). Every one of them
+    only grows, so the difference of two states is exact."""
+    from repro.core.spans import totals
+
+    counters = {k: v for k, v in vars(eng.metrics).items()
+                if type(v) in (int, float)}
+    parts = eng.collection.partitions
+    counters["uploads"] = sum(p.providers.uploads for p in parts)
+    counters["upload_bytes"] = sum(p.providers.upload_bytes for p in parts)
+    counters["repair_rows"] = sum(p.index.repair_rows for p in parts)
+    return counters, {n: (c, t) for n, (c, t, _) in totals().items()}
+
+
+def state_delta(before: tuple[dict, dict], after: tuple[dict, dict]):
+    """``after`` - ``before`` of two ``program_state``s: (counters,
+    spans), with a name that first appears in ``after`` counted from 0."""
+    (c0, s0), (c1, s1) = before, after
+    counters = {k: v - c0.get(k, 0) for k, v in c1.items()}
+    spans = {n: (c - s0.get(n, (0, 0))[0], t - s0.get(n, (0, 0))[1])
+             for n, (c, t) in s1.items()}
+    return counters, spans
 
 
 def annotate(name: str):
@@ -56,28 +92,27 @@ class Profile:
     ended: bool = False
     batches: int = 0  # micro-batches dispatched in the span
     answered: int = 0  # queries answered in the span
-    write_ops: int = 0  # write ops applied in the span
-    hops_weighted: float = 0.0  # the engine's search-round counters over
-    hops_lanes: int = 0  # the span's micro-batches
     sent: tuple = (0, 0)  # the queries handed over in the span: [lo, hi)
+    counters: dict = dataclasses.field(default_factory=dict)
+    spans: dict = dataclasses.field(default_factory=dict)
     _ann: object = None
     _at_start: dict = dataclasses.field(default_factory=dict)
+    _state: tuple = ({}, {})
 
-    def begin(self, counts: dict) -> None:
+    def begin(self, counts: dict, state: tuple) -> None:
         self.start_trace()
         self._ann = annotate(TRACED)
         self._ann.__enter__()
         self._at_start = dict(counts)
+        self._state = state
         self.started = True
 
-    def end(self, counts: dict) -> None:
+    def end(self, counts: dict, state: tuple) -> None:
         self._ann.__exit__(None, None, None)
         self.stop_trace()
+        self.counters, self.spans = state_delta(self._state, state)
         d = {key: counts[key] - self._at_start[key] for key in counts}
         self.batches, self.answered = d["batches"], d["answered"]
-        self.write_ops = d["write_ops"]
-        self.hops_weighted, self.hops_lanes = (d["hops_weighted"],
-                                               d["hops_lanes"])
         self.sent = (self._at_start["sent"], counts["sent"])
         self.ended = True
 
@@ -117,6 +152,9 @@ class WindowResult:
     ops_at_end: tuple[int, int]  # ... at writes_end
     wake_late: np.ndarray  # seconds each sleep overshot its due time
     batches: int  # micro-batches dispatched in the window
+    counters: dict  # the program's counters over the loop (state_delta)
+    spans: dict  # its span totals over the loop: name -> (count, ns)
+    state_s: np.ndarray  # seconds each program_state call took
 
     @property
     def latency_ms(self) -> np.ndarray:
@@ -141,6 +179,15 @@ def drive(svc, queries: np.ndarray, offsets: np.ndarray, seconds: float,
     eng = svc.engine
     max_batch = eng.cfg.max_batch
     n = len(offsets)
+    state_s: list[float] = []
+
+    def state() -> tuple[dict, dict]:
+        t = time.perf_counter()
+        out = program_state(eng)
+        state_s.append(time.perf_counter() - t)
+        return out
+
+    at_start = state()
     window = annotate("bench.window")
     window.__enter__()
     t0 = time.perf_counter()
@@ -164,17 +211,14 @@ def drive(svc, queries: np.ndarray, offsets: np.ndarray, seconds: float,
     answered = 0
 
     def counts() -> dict:
-        m = eng.metrics
-        return {"batches": batches, "answered": answered, "sent": i,
-                "write_ops": sum(stream.applied()[:2]) if stream else 0,
-                "hops_weighted": m.hops_weighted, "hops_lanes": m.hops_lanes}
+        return {"batches": batches, "answered": answered, "sent": i}
 
     try:
         while True:
             now = time.perf_counter()
             if (profile is not None and not profile.started
                     and now >= t0 + profile.offset):
-                profile.begin(counts())
+                profile.begin(counts(), state())
             writing = stream is not None and now < t_end
             if ops_end is None and now >= t_end:
                 ops_end = stream.applied()[:2] if stream else (0, 0)
@@ -225,15 +269,18 @@ def drive(svc, queries: np.ndarray, offsets: np.ndarray, seconds: float,
                 with annotate("harness.wait"):
                     time.sleep(min(0.001, t_end - now))
     finally:
+        at_end = state()
         if profile is not None and profile.started:
-            profile.end(counts())
+            profile.end(counts(), at_end)
         window.__exit__(None, None, None)
+    counters, spans = state_delta(at_start, at_end)
     return WindowResult(
         due=due, sent=sent, done=done, ids=ids, dists=dists, status=status,
         complete=complete, batch=batch, live_lo=live_lo, live_hi=live_hi,
         t0=t0, t_end=t_end, writes_end=writes_end,
         ops_at_start=tuple(ops_start), ops_at_end=tuple(ops_end),
         wake_late=np.asarray(wake_late), batches=batches,
+        counters=counters, spans=spans, state_s=np.asarray(state_s),
     )
 
 

@@ -4,6 +4,7 @@ span: the partition stats' ``hops`` as the engine accumulates them
 
 
 def read(run):
-    if run.traced is None or not run.traced.hops_lanes:
+    c = run.traced.counters if run.traced is not None else {}
+    if not c.get("hops_lanes"):
         return None
-    return run.traced.hops_weighted / run.traced.hops_lanes
+    return c["hops_weighted"] / c["hops_lanes"]

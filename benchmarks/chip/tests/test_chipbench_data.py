@@ -107,6 +107,7 @@ class FakeEngine:
         self.queue, self.out, self.rid, self.pumps = [], {}, 0, 0
         self.ingest_backlog = 0
         self.metrics = SimpleNamespace(hops_weighted=0.0, hops_lanes=0)
+        self.collection = SimpleNamespace(partitions=[])  # no partitions
 
     def submit_query(self, q, k, L, predicate=None):
         self.rid += 1
@@ -179,6 +180,7 @@ def test_the_traced_span_is_the_windows_end_and_counts_only_itself():
     lo, hi = prof.sent
     assert 0 < lo < hi == 40
     assert np.all(w.due[lo:hi] >= w.t0 + 0.29)
-    assert prof.answered == hi - lo and prof.hops_lanes == hi - lo
-    assert prof.hops_weighted == 20.0 * (hi - lo)
+    assert prof.answered == hi - lo
+    assert prof.counters["hops_lanes"] == hi - lo
+    assert prof.counters["hops_weighted"] == 20.0 * (hi - lo)
     assert 0 < prof.batches < w.batches

@@ -97,9 +97,9 @@ def test_metric_readers_on_the_recorded_trace():
     batch = np.full(n, 16)
     batch[120:160] = 4
     window = SimpleNamespace(status=np.full(n, 200), batch=batch)
-    traced = SimpleNamespace(batches=2, answered=20, write_ops=0,
-                             hops_weighted=900.0, hops_lanes=30,
-                             sent=(120, 160))
+    traced = SimpleNamespace(batches=2, answered=20, sent=(120, 160),
+                             counters={"hops_weighted": 900.0,
+                                       "hops_lanes": 30})
     run = SimpleNamespace(cell=cell, window=window, trace=t, traced=traced,
                           peaks=spec.load_peaks("TPU v5 lite"))
     read = {m.name: spec.metric_reader(m.name)(run) for m in cell.per_layer}
